@@ -38,11 +38,9 @@ import time
 def main(out_path: str = "BENCH_cache.json", *, n_rows: int = 1 << 16,
          smoke: bool = False, write: bool = True) -> dict:
     sys.path.insert(0, "src")
-    import jax
     import numpy as np
     from repro.columnar.table import Table
-    from repro.query import Catalog, CostModel, Executor, Q, QueryServer, \
-        SemanticCache, load_calibration
+    from repro.query import Catalog, Executor, Q, QueryServer, SemanticCache
 
     if smoke:
         n_rows = 1 << 13
@@ -57,16 +55,11 @@ def main(out_path: str = "BENCH_cache.json", *, n_rows: int = 1 << 16,
         "orderkey": np.asarray(rng.choice(40_000, size=4096, replace=False),
                                np.int32)})
     catalog = Catalog.from_tables(lineitem, orders)
-    calibration = load_calibration()
     report: dict = {"n_rows": n_rows, "n_templates": n_templates,
-                    "n_queries": n_queries,
-                    "calibrated": calibration is not None}
-
-    n_eng = len(jax.devices())
+                    "n_queries": n_queries}
 
     def make_executor(cat=catalog, **kw):
-        return Executor(cat, cost_model=CostModel(
-            n_eng, calibration=calibration), **kw)
+        return Executor(cat, **kw)
 
     # distinct join+filter+aggregate templates (distinct bounds => distinct
     # fingerprints; one shared compilation since bounds are traced)
@@ -319,4 +312,6 @@ def subsumption_smoke():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(smoke="--smoke" in sys.argv)

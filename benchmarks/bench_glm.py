@@ -173,6 +173,8 @@ def main(out_path: str = "BENCH_glm.json", *, n_rows: int = 1 << 16,
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8"
                         ).strip()
+    # host devices only: the parent may hold the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--sharded-child",
          str(min(n_rows, 1 << 13))],
@@ -211,6 +213,8 @@ def glm_smoke():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if "--sharded-child" in sys.argv:
         sys.path.insert(0, os.path.join(_ROOT, "src"))
         rows = int(sys.argv[sys.argv.index("--sharded-child") + 1])

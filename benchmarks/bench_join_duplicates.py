@@ -156,4 +156,6 @@ def join_duplicates():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print(json.dumps(main(smoke="--smoke" in sys.argv), indent=2))

@@ -163,4 +163,6 @@ def observability_figures():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(smoke="--smoke" in sys.argv)

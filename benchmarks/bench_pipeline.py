@@ -49,8 +49,7 @@ def main(out_path: str = "BENCH_pipeline.json", *, n_rows: int = 1 << 17,
     import numpy as np
     from repro.columnar.table import Table
     from repro.query import (
-        Catalog, CostModel, Executor, PlacementCapacityError, Q,
-        QueryServer, load_calibration,
+        Catalog, Executor, PlacementCapacityError, Q, QueryServer,
     )
 
     if smoke:
@@ -73,15 +72,10 @@ def main(out_path: str = "BENCH_pipeline.json", *, n_rows: int = 1 << 17,
                               size=4 * n_rows).astype(np.int32),
     })
     catalog = Catalog.from_tables(lineitem, orders, history)
-    calibration = load_calibration()
-    report: dict = {"n_rows": n_rows,
-                    "calibrated": calibration is not None}
+    report: dict = {"n_rows": n_rows}
 
     def make_executor(**kw):
-        n_eng = len(__import__("jax").devices())
-        return Executor(catalog,
-                        cost_model=CostModel(n_eng,
-                                             calibration=calibration), **kw)
+        return Executor(catalog, **kw)
 
     # --- streamed vs eager throughput + morsel sweep ------------------------
     ex = make_executor()
@@ -246,4 +240,6 @@ def main(out_path: str = "BENCH_pipeline.json", *, n_rows: int = 1 << 17,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(smoke="--smoke" in sys.argv)

@@ -114,4 +114,6 @@ def main(out_path: str = "BENCH_query.json", *, n_rows: int = 1 << 17,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(smoke="--smoke" in sys.argv)

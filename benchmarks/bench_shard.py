@@ -199,6 +199,9 @@ def main(out_path=_OUT, *, smoke=False, write=True) -> dict:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             " --xla_force_host_platform_device_count="
                             f"{_FORCED_DEVICES}").strip()
+    # the child simulates its mesh on host devices: it must never reach
+    # for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     args = [sys.executable, os.path.abspath(__file__), "--child"]
     if smoke:
         args.append("--smoke")
@@ -240,6 +243,8 @@ def shard_figures():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if "--child" in sys.argv:
         print(json.dumps(_bench("--smoke" in sys.argv)))
     else:

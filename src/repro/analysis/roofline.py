@@ -17,10 +17,11 @@ import dataclasses
 from typing import Optional
 
 from repro.configs.base import ArchConfig, ShapeConfig
+from repro.core.channels import V5E
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 4.95e10             # bytes/s / link (~50 GB/s)
+PEAK_FLOPS = V5E.flops                 # bf16 / chip
+HBM_BW = V5E.hbm_gbps * 1e9            # bytes/s / chip
+ICI_BW = V5E.ici_gbps * 1e9            # bytes/s / link
 
 
 @dataclasses.dataclass

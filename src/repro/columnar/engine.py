@@ -61,6 +61,10 @@ def select_range(table: Table, column: str, lo: int, hi: int, *,
         return Table(f"{table.name}.sel", {"idx": Column(idx, "idx")})
     while block > 1 and table.num_rows % (n_eng * block) != 0:
         block //= 2
+    if block % 128:
+        # the kernel's blocks tile the TPU's 128 lanes; a row count that
+        # only halves to a smaller block takes the XLA mask (same answer)
+        impl = "xla"
     idx, counts = sel_core.select_distributed(
         table.column(column), lo, hi, table.plan, block=block, impl=impl)
     flat = idx.reshape(-1)

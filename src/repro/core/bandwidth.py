@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.channels import ChannelPlan
 from repro.core.shim import plan_stream_block
@@ -42,7 +42,7 @@ def stream_copy_pallas(x, *, block: int = 0, interpret: bool = False):
 
 
 def stream_copy_distributed(x, plan: ChannelPlan, *, impl: str = "xla",
-                            interpret: bool = True):
+                            interpret: bool = False):
     """One TG per engine over the mesh."""
     def engine(x_local):
         if impl == "pallas":
@@ -51,7 +51,7 @@ def stream_copy_distributed(x, plan: ChannelPlan, *, impl: str = "xla",
 
     axis = plan.axis
     return shard_map(engine, mesh=plan.mesh, in_specs=(P(axis),),
-                     out_specs=P(axis), check_rep=False)(x)
+                     out_specs=P(axis), check_vma=False)(x)
 
 
 def measure_gbps(fn, x, *, iters: int = 5) -> float:

@@ -19,7 +19,7 @@ from typing import Literal
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # --- paper hardware model (AD9H7, 2 stacks x 16 pseudo channels) ----------- #
 N_PORTS = 32
@@ -31,9 +31,41 @@ PORT_GBPS_300 = 282.0 / 32
 CHANNEL_GBPS_200 = 14.0
 CHANNEL_GBPS_300 = 21.0
 
-# --- TPU v5e model --------------------------------------------------------- #
-TPU_HBM_GBPS = 819.0
-TPU_ICI_GBPS = 49.5
+# --- device peak rates, keyed by jax's ``device_kind`` -------------------- #
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks the cost model prices with."""
+    hbm_gbps: float          # HBM bandwidth, GB/s
+    ici_gbps: float          # chip-to-chip bandwidth per link, GB/s
+    flops: float             # bf16 FLOP/s
+    source: str
+
+
+V5E = DevicePeaks(
+    hbm_gbps=819.0, ici_gbps=50.0, flops=197e12,
+    source="Google Cloud documentation, 'TPU v5e': 819 GB/s HBM, "
+           "197 TFLOP/s bf16, 1,600 Gbit/s ICI per chip over 4 links")
+
+PEAKS = {
+    "TPU v5 lite": V5E,      # what jax reports for a v5e chip
+    # the CPU backend has no peak worth pricing: tests and CPU rehearsals
+    # price as a v5e, so a plan made there is the plan the chip would make
+    "cpu": dataclasses.replace(V5E, source="test entry: the v5e numbers"),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of one device kind; a kind without an entry is an error, not
+    a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}: add its "
+            "published HBM/ICI/FLOP peaks to repro.core.channels.PEAKS"
+        ) from None
 
 
 def fpga_bandwidth_model(n_ports: int, separation_mib: int,
@@ -55,13 +87,15 @@ def fpga_bandwidth_model(n_ports: int, separation_mib: int,
     return total
 
 
-def tpu_bandwidth_model(n_engines: int, partitioned: bool) -> float:
+def tpu_bandwidth_model(n_engines: int, partitioned: bool,
+                        peaks: DevicePeaks = V5E) -> float:
     """TPU analogue: engines = chips.  Partitioned -> each chip streams its
     local HBM; congested -> every chip pulls the same chip's shard over ICI
     (the crossbar-congestion analogue)."""
     if partitioned:
-        return n_engines * TPU_HBM_GBPS
-    return min(TPU_HBM_GBPS, n_engines * TPU_ICI_GBPS / max(n_engines - 1, 1))
+        return n_engines * peaks.hbm_gbps
+    return min(peaks.hbm_gbps,
+               n_engines * peaks.ici_gbps / max(n_engines - 1, 1))
 
 
 Placement = Literal["partitioned", "congested", "replicated"]
@@ -69,11 +103,22 @@ Placement = Literal["partitioned", "congested", "replicated"]
 
 @dataclasses.dataclass(frozen=True)
 class ChannelPlan:
-    """Placement of a 1-D column across the mesh's 'engine' axis."""
+    """Placement of a 1-D column across the mesh's 'engine' axis.
+
+    The plan holds its mesh with Auto axes whatever the caller built
+    (``jax.make_mesh`` makes Explicit ones): an array placed on an
+    Explicit axis carries that axis in its type, and eager ops outside a
+    mesh context (``jnp.nonzero``, ``jnp.searchsorted``) then refuse it."""
 
     mesh: Mesh
     axis: str
     placement: Placement
+
+    def __post_init__(self) -> None:
+        if any(t != AxisType.Auto for t in self.mesh.axis_types):
+            object.__setattr__(self, "mesh", Mesh(
+                self.mesh.devices, self.mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(self.mesh.axis_names)))
 
     @property
     def n_engines(self) -> int:
@@ -88,8 +133,10 @@ class ChannelPlan:
         return jax.device_put(x, self.sharding())
 
     def predicted_gbps(self) -> float:
+        kind = self.mesh.devices.flat[0].device_kind
         return tpu_bandwidth_model(self.n_engines,
-                                   self.placement == "partitioned")
+                                   self.placement == "partitioned",
+                                   device_peaks(kind))
 
     def align_morsel_rows(self, rows: int) -> int:
         """Round a morsel row count up to a multiple of the engine count so

@@ -10,12 +10,13 @@ per S block), reproducing the linear regime of Fig. 8b.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.channels import ChannelPlan
 from repro.distributed import sharding as shardlib
@@ -30,7 +31,7 @@ HT_CAPACITY = 8192            # tuples per pass — the paper's URAM budget
 def join_distributed(s_keys, l_keys, plan: ChannelPlan, *,
                      table_size: int = 4 * HT_CAPACITY,
                      probe_depth: int = 8, block: int = DEFAULT_BLOCK,
-                     impl: str = "xla", interpret: bool = True):
+                     impl: str = "xla", interpret: bool = False):
     """s_keys (N_S,) replicated; l_keys (N_L,) partitioned per plan.
     Returns (s_idx per L position (N_L,), total matches).
 
@@ -67,7 +68,7 @@ def join_distributed(s_keys, l_keys, plan: ChannelPlan, *,
         return s_idx, count[None], dropped_max[None]
 
     fn = shard_map(engine, mesh=mesh, in_specs=(P(axis),),
-                   out_specs=(P(axis), P(axis), P(axis)), check_rep=False)
+                   out_specs=(P(axis), P(axis), P(axis)), check_vma=False)
     s_idx, counts, dropped = fn(l_keys)
     if not isinstance(dropped, jax.core.Tracer):
         # eager callers get the exactness bound surfaced; under jit the
@@ -86,7 +87,7 @@ def join_distributed(s_keys, l_keys, plan: ChannelPlan, *,
 def join_distributed_multi(s_keys, l_keys, plan: ChannelPlan, *,
                            max_out_per_shard: int = None,
                            block: int = DEFAULT_BLOCK,
-                           impl: str = "xla", interpret: bool = True):
+                           impl: str = "xla", interpret: bool = False):
     """Duplicate-capable scale-out join: s_keys (N_S,) replicated (may hold
     duplicate keys), l_keys (N_L,) partitioned per plan.  Keys must be in
     [0, 2**31 - 2]: negative values collide with the multi-pass padding
@@ -145,14 +146,14 @@ def join_distributed_multi(s_keys, l_keys, plan: ChannelPlan, *,
 
     fn = shard_map(engine, mesh=mesh, in_specs=(P(axis),),
                    out_specs=(P(axis), P(axis), P(axis), P(axis)),
-                   check_rep=False)
+                   check_vma=False)
     return fn(l_keys)
 
 
 def join_distributed_multi_result(s_keys, l_keys, plan: ChannelPlan, *,
                                   max_out_per_shard: int = None,
                                   block: int = DEFAULT_BLOCK,
-                                  impl: str = "xla", interpret: bool = True
+                                  impl: str = "xla", interpret: bool = False
                                   ) -> join_ops.MultiJoinResult:
     """``join_distributed_multi`` under the ``MultiJoinResult`` contract.
 
@@ -198,11 +199,16 @@ def _round_build_cap(cap: int) -> int:
     return cap if cap <= HT_CAPACITY else -(-cap // HT_CAPACITY) * HT_CAPACITY
 
 
+# one program per (shapes, capacities): run eagerly, shard_map would
+# dispatch every op of the body on its own, each compiled apart
+@functools.partial(jax.jit, static_argnames=(
+    "layout", "s_cap", "l_cap", "max_out_per_shard", "block", "impl",
+    "interpret"))
 def join_shuffle_multi(s_keys, l_keys, layout: "shardlib.ShardLayout", *,
                        s_cap: int = None, l_cap: int = None,
                        max_out_per_shard: int = None,
                        block: int = DEFAULT_BLOCK,
-                       impl: str = "xla", interpret: bool = True):
+                       impl: str = "xla", interpret: bool = False):
     """Shuffle-repartitioned duplicate-capable join (the costed alternative
     to broadcasting the build side).
 
@@ -277,7 +283,7 @@ def join_shuffle_multi(s_keys, l_keys, layout: "shardlib.ShardLayout", *,
         return l_buf, s_buf, total[None], (total > max_out)[None]
 
     fn = shard_map(engine, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=(P(axis),) * 4, check_rep=False)
+                   out_specs=(P(axis),) * 4, check_vma=False)
     l_buf, s_buf, totals, pair_over = fn(s_bkeys, l_bkeys)
     valid = l_buf >= 0
     l_idx = jnp.where(valid, l_bids.reshape(-1)[jnp.clip(l_buf, 0)], -1)

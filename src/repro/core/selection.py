@@ -13,7 +13,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.channels import ChannelPlan
 from repro.kernels.selection import ops as sel_ops
@@ -22,7 +22,7 @@ from repro.kernels.selection.selection import DEFAULT_BLOCK
 
 def select_distributed(x, lo, hi, plan: ChannelPlan, *,
                        block: int = DEFAULT_BLOCK, impl: str = "xla",
-                       interpret: bool = True):
+                       interpret: bool = False):
     """x: (N,) int32 placed per ``plan``. Returns (idx lines (N,), per-engine
     counts (n_engines,)). Indices are GLOBAL (engine offset applied)."""
     mesh, axis = plan.mesh, plan.axis
@@ -40,7 +40,7 @@ def select_distributed(x, lo, hi, plan: ChannelPlan, *,
 
     in_spec = P(axis) if plan.placement == "partitioned" else P()
     fn = shard_map(engine, mesh=mesh, in_specs=(in_spec,),
-                   out_specs=(P(axis), P(axis)), check_rep=False)
+                   out_specs=(P(axis), P(axis)), check_vma=False)
     if plan.placement == "partitioned":
         return fn(x)
     # congested mode: every engine scans the SAME first shard (crossbar

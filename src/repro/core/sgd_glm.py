@@ -19,7 +19,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.channels import ChannelPlan
 from repro.kernels.sgd import ops as sgd_ops
@@ -54,7 +54,7 @@ def pad_to_minibatch(a, b, minibatch: int):
 def hyperparam_search(a, b, grid: Sequence[HyperParams], plan: ChannelPlan,
                       *, minibatch: int = 16, epochs: int = 10,
                       kind: str = "logreg", impl: str = "xla",
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Train len(grid) models in parallel; jobs round-robin over engines.
 
     a (m, n) f32, b (m,): replicated per plan.  Returns xs (K, n) and final
@@ -89,7 +89,7 @@ def hyperparam_search(a, b, grid: Sequence[HyperParams], plan: ChannelPlan,
         return xs[None], losses[None]
 
     fn = shard_map(engine, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=(P(axis), P(axis)), check_rep=False)
+                   out_specs=(P(axis), P(axis)), check_vma=False)
     xs, losses = fn(lrs, l2s)
     return (xs.reshape(k_pad, n)[:k], losses.reshape(k_pad)[:k])
 

@@ -13,8 +13,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize_int8(x):

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 
@@ -43,7 +43,7 @@ def cp_decode_attention(mesh, axis: str, q, k, v, k_valid):
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(), P(None, axis), P(None, axis),
                              P(None, axis)),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(q, k, v, k_valid)
 
 

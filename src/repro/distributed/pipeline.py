@@ -19,7 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
@@ -80,5 +80,5 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, params_stacked,
 
     fn = shard_map(worker, mesh=mesh,
                    in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(params_stacked, x)

@@ -13,7 +13,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention
 @partial(jax.jit, static_argnames=("causal", "impl", "interpret", "block_q",
                                    "block_kv"))
 def attend(q, k, v, *, causal: bool = True, impl: str = "xla",
-           interpret: bool = True, block_q: int = 128, block_kv: int = 128):
+           interpret: bool = False, block_q: int = 128, block_kv: int = 128):
     """q (B,S,H,D); k/v (B,S,H,D) (kv already expanded to q heads)."""
     b, s, h, d = q.shape
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)

@@ -51,7 +51,7 @@ class MultiJoinResult(NamedTuple):
                                    "impl", "interpret"))
 def hash_join(s_keys, l_keys, *, table_size: int, probe_depth: int = 4,
               block: int = DEFAULT_BLOCK, impl: str = "xla",
-              interpret: bool = True):
+              interpret: bool = False):
     """End-to-end naively-partitioned hash join (Algorithm 2), unique S.
 
     Build uses the (cheap, small-S) vectorized sequential-equivalent build;
@@ -96,7 +96,7 @@ def hash_join(s_keys, l_keys, *, table_size: int, probe_depth: int = 4,
 def hash_join_multi(s_keys, l_keys, *, max_out: int,
                     cap: int = DEFAULT_MATCH_CAP,
                     block: int = DEFAULT_BLOCK, impl: str = "xla",
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Duplicate-capable multi-match join: the exact (l_idx, s_idx) pair
     multiset of ``s_keys ⋈ l_keys``, materialized into a (max_out,) pair
     list ordered by (probe row, bucket position).

@@ -17,7 +17,7 @@ from repro.kernels.selection.selection import DEFAULT_BLOCK, select_pallas
 
 @partial(jax.jit, static_argnames=("block", "impl", "interpret"))
 def select(x, lo, hi, *, block: int = DEFAULT_BLOCK, impl: str = "xla",
-           interpret: bool = True):
+           interpret: bool = False):
     """Range selection -> (padded index lines (N,), per-block counts)."""
     if impl == "pallas":
         return select_pallas(x, lo, hi, block=block, interpret=interpret)
@@ -27,7 +27,7 @@ def select(x, lo, hi, *, block: int = DEFAULT_BLOCK, impl: str = "xla",
 
 @partial(jax.jit, static_argnames=("block", "impl", "interpret"))
 def select_count(x, lo, hi, *, block: int = DEFAULT_BLOCK, impl: str = "xla",
-                 interpret: bool = True):
+                 interpret: bool = False):
     _, counts = select(x, lo, hi, block=block, impl=impl, interpret=interpret)
     return jnp.sum(counts)
 

@@ -12,11 +12,10 @@ chips, see core/selection.py).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK = 4096      # int32 elements per grid step: 16 KiB in VMEM
 
@@ -31,34 +30,41 @@ def _selection_kernel(lo_ref, hi_ref, x_ref, idx_ref, cnt_ref):
     iota = jax.lax.broadcasted_iota(jnp.int32, (block,), 0) + base
     mask = (x >= lo) & (x <= hi)
     idx_ref[...] = jnp.where(mask, iota, -1)
-    cnt_ref[0] = jnp.sum(mask.astype(jnp.int32))
+    cnt_ref[...] = jnp.full(cnt_ref.shape, jnp.sum(mask.astype(jnp.int32)),
+                            jnp.int32)
 
 
 def select_pallas(x, lo, hi, *, block: int = DEFAULT_BLOCK,
                   interpret: bool = False):
     """x: (N,) int32, N % block == 0. Returns (idx (N,) with -1 dummies,
-    counts (N/block,))."""
+    counts (N/block,)).
+
+    On a TPU ``block`` must be a multiple of 128 (the lane tiling of a
+    rank-1 block).  Each block's count leaves the kernel as one lane-dense
+    (1, 128) line — a rank-1 block of one element is not tileable — and
+    the bounds ride in SMEM as scalars."""
     n = x.shape[0]
     assert n % block == 0, (n, block)
     nb = n // block
     lo = jnp.asarray([lo], x.dtype)
     hi = jnp.asarray([hi], x.dtype)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     idx, cnt = pl.pallas_call(
         _selection_kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),               # lo (SMEM-ish)
-            pl.BlockSpec((1,), lambda i: (0,)),               # hi
-            pl.BlockSpec((block,), lambda i: (i,)),           # column block
+            smem,                                              # lo
+            smem,                                              # hi
+            pl.BlockSpec((block,), lambda i: (i,)),            # column block
         ],
         out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),           # index line
-            pl.BlockSpec((1,), lambda i: (i,)),               # per-block count
+            pl.BlockSpec((block,), lambda i: (i,)),            # index line
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),    # block count
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, 128), jnp.int32),
         ],
         interpret=interpret,
     )(lo, hi, x)
-    return idx, cnt
+    return idx, cnt[:, 0, 0]

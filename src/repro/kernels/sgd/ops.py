@@ -14,7 +14,7 @@ from repro.kernels.sgd.sgd import sgd_pallas
                                    "impl", "interpret"))
 def sgd_train(a, b, x0, *, lr: float, l2: float = 0.0, minibatch: int = 16,
               epochs: int = 1, kind: str = "ridge", impl: str = "xla",
-              interpret: bool = True):
+              interpret: bool = False):
     if impl == "pallas":
         return sgd_pallas(a, b, x0, lr=lr, l2=l2, minibatch=minibatch,
                           epochs=epochs, kind=kind, interpret=interpret)

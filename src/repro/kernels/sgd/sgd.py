@@ -20,12 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are a no-op under interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_TPU = True
-except Exception:                                     # pragma: no cover
-    _HAS_TPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _sgd_kernel(a_ref, b_ref, x0_ref, xout_ref, x_vmem, *,
@@ -37,13 +32,15 @@ def _sgd_kernel(a_ref, b_ref, x0_ref, xout_ref, x_vmem, *,
         x_vmem[...] = x0_ref[...]
 
     a = a_ref[...]                                   # (B, n) minibatch block
-    b = b_ref[...]                                   # (B,)
-    x = x_vmem[...]
-    z = jnp.dot(a, x, preferred_element_type=jnp.float32)        # Dot
+    b = b_ref[...]                                   # (B, 1)
+    x = x_vmem[...]                                  # (1, n)
+    z = jax.lax.dot_general(a, x, (((1,), (1,)), ((), ())),      # Dot
+                            preferred_element_type=jnp.float32)  # (B, 1)
     if kind == "logreg":
         z = jax.nn.sigmoid(z)                        # ScalarEngine
     d = z - b
-    g = jnp.dot(d, a, preferred_element_type=jnp.float32) / a.shape[0]
+    g = jax.lax.dot_general(d, a, (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32) / a.shape[0]
     x = x - lr * (g + 2.0 * l2 * x)                  # Update (RAW preserved)
     x_vmem[...] = x
 
@@ -55,27 +52,29 @@ def _sgd_kernel(a_ref, b_ref, x0_ref, xout_ref, x_vmem, *,
 def sgd_pallas(a, b, x0, *, lr: float, l2: float = 0.0, minibatch: int = 16,
                epochs: int = 1, kind: str = "ridge",
                interpret: bool = False):
-    """a: (m, n) f32; b: (m,); x0: (n,). Returns trained x (n,)."""
+    """a: (m, n) f32; b: (m,); x0: (n,). Returns trained x (n,).
+
+    Labels and the model travel as 2-D (m, 1) / (1, n) blocks: the TPU
+    lowering tiles rank-1 blocks by 128 lanes, which a 16-row minibatch
+    of labels is not."""
     m, n = a.shape
     assert m % minibatch == 0
     nb = m // minibatch
     kernel = functools.partial(_sgd_kernel, lr=lr, l2=l2, kind=kind,
                                nb=nb, epochs=epochs)
-    kwargs = {}
-    if _HAS_TPU and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))      # sequential: RAW dep
-    return pl.pallas_call(
+    x = pl.pallas_call(
         kernel,
         grid=(nb * epochs,),
         in_specs=[
             pl.BlockSpec((minibatch, n), lambda i: (i % nb, 0)),
-            pl.BlockSpec((minibatch,), lambda i: (i % nb,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec((minibatch, 1), lambda i: (i % nb, 0)),
+            pl.BlockSpec((1, n), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((n,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),     # sequential: RAW dep
         interpret=interpret,
-        **kwargs,
-    )(a, b, x0)
+    )(a, b.reshape(m, 1), x0.reshape(1, n))
+    return x.reshape(n)

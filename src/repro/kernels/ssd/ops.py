@@ -12,7 +12,7 @@ from repro.models.mamba import ssd_chunked
 
 @partial(jax.jit, static_argnames=("chunk", "impl", "interpret"))
 def ssd(x, dt, a_log, b, c, d_skip, *, chunk: int = 128, impl: str = "xla",
-        interpret: bool = True):
+        interpret: bool = False):
     """Model layout: x (B,S,nh,hd); dt (B,S,nh); b/c (B,S,ng,ds).
     Returns (y (B,S,nh,hd), h_final (B,nh,hd,ds))."""
     if impl == "xla":

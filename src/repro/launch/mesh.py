@@ -12,18 +12,28 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    # jax.make_mesh defaults to Explicit axes, under which
+    # with_sharding_constraint asserts instead of resharding; the model
+    # code and the query layer are written for Auto axes
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
-def make_host_mesh():
-    """Mesh over whatever devices exist (smoke tests: a single CPU)."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+def make_host_mesh(devices=None):
+    """(1, n) ("data", "model") mesh over ``devices`` (default: every
+    device that exists)."""
+    devices = list(jax.devices() if devices is None else devices)
+    return jax.make_mesh((1, len(devices)), ("data", "model"), _auto(2),
+                         devices=devices)
 
 
 def mesh_axis(mesh, name: str) -> int:

@@ -7,23 +7,21 @@ numbers: partitioned columns stream every channel, a congested layout
 collapses to crossbar bandwidth, and a build side must be replicated per
 engine.  This module prices each (impl, placement, pass-count) alternative
 of every physical operator with ``channels.tpu_bandwidth_model`` /
-``channels.fpga_bandwidth_model`` plus the roofline constants, so the
-executor can pick placement per column instead of requiring callers to
-pre-``place()`` tables.
+``channels.fpga_bandwidth_model`` plus the running device's peak rates
+(``channels.PEAKS``), so the executor can pick placement per column
+instead of requiring callers to pre-``place()`` tables.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import math
-import os
 from typing import Dict, Optional, Tuple
 
 import jax
 
-from repro.analysis.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 from repro.core.channels import (
-    fpga_bandwidth_model, tpu_bandwidth_model, TPU_HBM_GBPS,
+    device_peaks, fpga_bandwidth_model, tpu_bandwidth_model,
 )
 from repro.core.join import HT_CAPACITY
 from repro.query import logical as L
@@ -32,9 +30,9 @@ BYTES_PER_VALUE = 4                 # int32/float32 columns
 
 # streaming efficiencies + fixed launch overheads (sec) per operator —
 # the crossover that makes the xla/pallas choice size-dependent.  These
-# are the DEFAULTS; measured per-backend numbers from
-# benchmarks/run.py (BENCH_calibration.json) override them per model
-# instance (``load_calibration`` / CostModel(calibration=...)).
+# are the DEFAULTS; numbers measured on the running device by
+# benchmarks/calibrate.py override them when passed in explicitly
+# (``CostModel(calibration=load_calibration(path))``).
 XLA_STREAM_EFF = 0.70
 PALLAS_STREAM_EFF = 0.92
 XLA_CALL_OVERHEAD = 2e-6
@@ -68,28 +66,38 @@ DISK_GBPS = 2.0            # sequential NVMe read into page cache
 # fills in this order and the cache evicts only from the last entry
 TIERS = ("device", "host", "disk")
 
-CALIBRATION_FILE = "BENCH_calibration.json"
+# operators whose ``pallas`` alternative dispatches a kernel that compiles
+# for the TPU (tests/test_tpu_compile.py).  The join probes are absent:
+# the TPU compiler refuses the multi-match probes' 1-D in-kernel gather
+# ("Only 2D gather is supported") and the unique probe's rank-1 count
+# block of one element, so joins price and run on XLA only
+PALLAS_OPS = frozenset({"filter", "filter_project"})
 
 
-def load_calibration(path: Optional[str] = None) -> Optional[dict]:
-    """Measured per-backend stream efficiencies / call overheads emitted by
-    ``benchmarks/run.py``.  Returns None (fixed constants apply) when the
-    file is absent or unreadable — calibration is an overlay, never a
-    requirement.  The ``REPRO_CALIBRATION`` env var overrides the default
-    CWD lookup: a path loads that file, ``off``/``0`` disables the
-    overlay entirely (so plan decisions never silently depend on what a
-    benchmark run left in the working directory)."""
-    if path is None:
-        env = os.environ.get("REPRO_CALIBRATION", "")
-        if env.lower() in ("off", "0", "none"):
-            return None
-        path = env or os.path.join(os.getcwd(), CALIBRATION_FILE)
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except (OSError, ValueError):
-        return None
-    return data if isinstance(data, dict) and "backends" in data else None
+def load_calibration(path: str, *, backend: Optional[str] = None,
+                     device_kind: Optional[str] = None) -> dict:
+    """Measured stream efficiencies / call overheads as written by
+    ``benchmarks/calibrate.py``.  A calibration prices only the device it
+    was measured on, so a file whose ``backend`` — or ``device_kind``,
+    where it records one — differs from ``backend``/``device_kind``
+    (default: the running device's) is refused with ``ValueError``."""
+    with open(path) as f:
+        data = json.load(f)
+    if not isinstance(data, dict) or "backends" not in data:
+        raise ValueError(f"{path} is not a calibration file")
+    dev = jax.devices()[0]
+    want = backend or dev.platform
+    if data.get("backend") != want:
+        raise ValueError(
+            f"{path} was measured on backend {data.get('backend')!r}, "
+            f"not on {want!r}: recalibrate on this device "
+            "(benchmarks/calibrate.py) or pass no calibration")
+    kind = device_kind or dev.device_kind
+    if "device_kind" in data and data["device_kind"] != kind:
+        raise ValueError(
+            f"{path} was measured on a {data['device_kind']!r}, not on a "
+            f"{kind!r}: recalibrate on this device or pass no calibration")
+    return data
 
 
 # --------------------------------------------------------------------------- #
@@ -232,17 +240,21 @@ def expected_chain_length(node: L.Node, column: str,
 class CostModel:
     """Prices one physical operator alternative at a time.
 
-    ``hardware="tpu"`` uses the mesh/ICI analogue; ``hardware="fpga"``
-    prices with the paper's calibrated AD9H7 channel model (32 ports,
-    256 MiB separation when partitioned, 0 when congested) — the same
-    decision procedure on either bandwidth curve.
+    ``hardware="tpu"`` uses the mesh/ICI analogue with the peaks of
+    ``device_kind`` (default: the running device's; an unknown kind
+    raises); ``hardware="fpga"`` prices with the paper's calibrated AD9H7
+    channel model (32 ports, 256 MiB separation when partitioned, 0 when
+    congested) — the same decision procedure on either bandwidth curve.
     """
 
     def __init__(self, n_engines: int, *, n_shards: int = 1,
                  hardware: str = "tpu",
                  allow_pallas: Optional[bool] = None,
-                 calibration: Optional[dict] = None):
+                 calibration: Optional[dict] = None,
+                 device_kind: Optional[str] = None):
         self.n_engines = n_engines
+        self.peaks = device_peaks(device_kind
+                                  or jax.devices()[0].device_kind)
         # explicit shard_map striping width (device = pseudo-channel);
         # 1 = the classic single-pipeline plans, byte-for-byte unchanged
         self.n_shards = max(int(n_shards), 1)
@@ -338,8 +350,12 @@ class CostModel:
             snap[key] = getattr(self, key)
         return snap
 
-    def impls(self) -> Tuple[str, ...]:
-        return ("xla", "pallas") if self.allow_pallas else ("xla",)
+    def impls(self, op: str) -> Tuple[str, ...]:
+        """Implementations priced for ``op``: pallas only where allowed and
+        where the op has a kernel that compiles."""
+        if self.allow_pallas and op in PALLAS_OPS:
+            return ("xla", "pallas")
+        return ("xla",)
 
     def bandwidth_gbps(self, placement: str) -> float:
         """Aggregate streaming bandwidth of one operator under a placement.
@@ -364,12 +380,12 @@ class CostModel:
         if placement == "sharded":
             # one device per shard streaming its own HBM, summed — the
             # TPU analogue of the channel-count sweep
-            return tpu_bandwidth_model(self.n_shards, True)
+            return tpu_bandwidth_model(self.n_shards, True, self.peaks)
         if placement == "partitioned":
-            return tpu_bandwidth_model(self.n_engines, True)
+            return tpu_bandwidth_model(self.n_engines, True, self.peaks)
         if placement == "congested":
-            return tpu_bandwidth_model(self.n_engines, False)
-        return TPU_HBM_GBPS            # replicated: one engine, local HBM
+            return tpu_bandwidth_model(self.n_engines, False, self.peaks)
+        return self.peaks.hbm_gbps     # replicated: one engine, local HBM
 
     def shuffle_cost(self, n_bytes: float) -> float:
         """Seconds to hash-repartition ``n_bytes`` across the shard mesh.
@@ -381,14 +397,15 @@ class CostModel:
         broadcast join decision trades against rescan passes."""
         if self.n_shards <= 1:
             return 0.0
-        return n_bytes * (self.n_shards - 1) / self.n_shards / ICI_BW
+        return n_bytes * (self.n_shards - 1) / self.n_shards \
+            / (self.peaks.ici_gbps * 1e9)
 
     def shard_broadcast_cost(self, n_bytes: float) -> float:
         """Replicating a build side to every SHARD over the interconnect
         (the broadcast strategy's repartition-analogue term)."""
         if self.n_shards <= 1:
             return 0.0
-        return n_bytes * (self.n_shards - 1) / ICI_BW
+        return n_bytes * (self.n_shards - 1) / (self.peaks.ici_gbps * 1e9)
 
     def stream_cost(self, n_bytes: float, *, impl: str, placement: str,
                     n_passes: int = 1, flops: float = 0.0) -> float:
@@ -398,14 +415,14 @@ class CostModel:
         over = self.call_overhead.get(impl, XLA_CALL_OVERHEAD)
         bw = self.bandwidth_gbps(placement) * 1e9 * eff
         t_mem = n_passes * n_bytes / bw
-        t_compute = flops / PEAK_FLOPS
+        t_compute = flops / self.peaks.flops
         return max(t_mem, t_compute) + over * n_passes
 
     def broadcast_cost(self, n_bytes: float) -> float:
         """Replicating a build side / dataset to every engine over ICI."""
         if self.n_engines <= 1:
             return 0.0
-        return n_bytes * (self.n_engines - 1) / ICI_BW
+        return n_bytes * (self.n_engines - 1) / (self.peaks.ici_gbps * 1e9)
 
     # -- semantic-cache pricing (recompute-cost vs residency-bytes) --------- #
 
@@ -594,11 +611,13 @@ class PhysNode:
                 f"{morsel}{strat}")
 
 
-def _choose(model: CostModel, n_bytes: float, placements: Tuple[str, ...],
-            *, n_passes: int = 1, flops: float = 0.0):
-    """argmin over impl x placement; returns (impl, placement, cost, alts)."""
+def _choose(model: CostModel, op: str, n_bytes: float,
+            placements: Tuple[str, ...], *, n_passes: int = 1,
+            flops: float = 0.0):
+    """argmin over impl x placement for ``op``; returns (impl, placement,
+    cost, alts)."""
     alts = {}
-    for impl in model.impls():
+    for impl in model.impls(op):
         for pl in placements:
             alts[f"{impl}/{pl}"] = model.stream_cost(
                 n_bytes, impl=impl, placement=pl, n_passes=n_passes,
@@ -643,7 +662,7 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
             return PhysNode("scan", node, "xla", "replicated", 1, rows,
                             cost, model.bandwidth_gbps("replicated"),
                             {"xla/replicated": cost}, n_bytes=n_bytes)
-        impl, pl, cost, alts = _choose(model, n_bytes,
+        impl, pl, cost, alts = _choose(model, "scan", n_bytes,
                                        _stream_placements(model))
         return PhysNode("scan", node, impl, pl, 1, rows, cost,
                         model.bandwidth_gbps(pl), alts, n_bytes=n_bytes)
@@ -657,9 +676,9 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
             * n_out_cols
         placements = ("replicated",) if role == "build" \
             else _stream_placements(model)
-        impl, pl, cost, alts = _choose(model, n_bytes, placements)
         op = "filter_project" if isinstance(node, L.FilterProject) \
             else "filter"
+        impl, pl, cost, alts = _choose(model, op, n_bytes, placements)
         return PhysNode(op, node, impl, pl, 1, rows, cost,
                         model.bandwidth_gbps(pl), alts, (child,),
                         n_bytes=n_bytes)
@@ -701,7 +720,7 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
         # placement would optimize a decision execution never consults
         probe_pl = left.placement if left.placement != "replicated" \
             else _stream_placements(model)[0]
-        impl, pl, cost, alts = _choose(model, n_bytes, (probe_pl,),
+        impl, pl, cost, alts = _choose(model, op, n_bytes, (probe_pl,),
                                        n_passes=n_passes)
         shard_strategy = None
         if model.n_shards > 1 and pl == "sharded":
@@ -758,7 +777,7 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
     if isinstance(node, L.Project):
         child = plan_physical(node.child, stats, model, role=role)
         n_bytes = rows * BYTES_PER_VALUE * len(node.columns)
-        impl, pl, cost, alts = _choose(model, n_bytes,
+        impl, pl, cost, alts = _choose(model, "project", n_bytes,
                                        _stream_placements(model)[:1])
         return PhysNode("project", node, impl, pl, 1, rows, cost,
                         model.bandwidth_gbps(pl), alts, (child,),
@@ -768,7 +787,7 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
         child = plan_physical(node.child, stats, model, role=role)
         in_rows = estimate_rows(node.child, stats, corr)
         n_bytes = in_rows * BYTES_PER_VALUE
-        impl, pl, cost, alts = _choose(model, n_bytes,
+        impl, pl, cost, alts = _choose(model, "aggregate", n_bytes,
                                        _stream_placements(model)[:1])
         # streaming granularity for the whole pipeline this aggregate
         # roots: priced on the probe-spine base scan (the stream source)
@@ -846,7 +865,7 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
         # one pass over the feature columns plus the written score column;
         # the cached weight vector is noise
         n_bytes = in_rows * BYTES_PER_VALUE * d + rows * BYTES_PER_VALUE
-        impl, pl, cost, alts = _choose(model, n_bytes,
+        impl, pl, cost, alts = _choose(model, "score_glm", n_bytes,
                                        _stream_placements(model)[:1],
                                        flops=2.0 * in_rows * d)
         return PhysNode("score_glm", node, impl, pl, 1, rows, cost,

@@ -45,7 +45,7 @@ from repro.query import telemetry as tm
 from repro.query.cache import SemanticCache, cache_disabled
 from repro.query.cost import (
     BYTES_PER_VALUE, ColumnStats, CostModel, PhysNode, TableStats,
-    column_placements, key_is_unique, load_calibration, plan_physical,
+    column_placements, key_is_unique, plan_physical,
 )
 from repro.query.optimize import optimize
 from repro.query.tiering import (
@@ -211,13 +211,17 @@ class Executor:
                 mesh = jax.sharding.Mesh(
                     np.array(jax.devices()[:n_sh]).reshape(1, n_sh),
                     ("data", "model"))
-        self.mesh = mesh if mesh is not None else make_host_mesh()
+        # no mesh and no shards: ONE device.  More devices are asked for
+        # with ``shards`` or an explicit mesh, never taken because they
+        # happen to be visible
+        self.mesh = mesh if mesh is not None \
+            else make_host_mesh(jax.devices()[:1])
         self.axis = axis
         n_eng = self.mesh.shape[axis]
-        # default model picks up measured per-backend numbers when
-        # benchmarks/run.py has emitted BENCH_calibration.json in the CWD
-        self.cost_model = cost_model or CostModel(
-            n_eng, calibration=load_calibration(), n_shards=n_sh)
+        # default model: the fixed constants.  A measured calibration is
+        # passed in explicitly (CostModel(calibration=load_calibration(
+        # path))), never picked up from the working directory
+        self.cost_model = cost_model or CostModel(n_eng, n_shards=n_sh)
         if self.shard_layout is not None \
                 and self.cost_model.n_shards != n_sh:
             # a caller-supplied model prices what this executor runs
@@ -364,10 +368,9 @@ class Executor:
         """Fold a calibration overlay into the live cost model and bump
         the cost-model EPOCH — the serve-side recalibration entry point.
 
-        ``calibration=None`` re-reads ``BENCH_calibration.json`` (the
-        construction-time source), so a long-lived server can pick up a
-        fresh offline benchmark run; passing a dict (usually
-        ``ledger.calibration_overlay(model)``) applies online evidence.
+        ``calibration`` (usually ``ledger.calibration_overlay(model)``, or
+        a fresh ``load_calibration(path)``) overlays measured prices;
+        ``None`` folds only the ledger's selectivity corrections.
         Application is idempotent (the model re-baselines against its
         pristine constants), and every cost-derived memo is flushed:
         memoized (opt, phys) plans and fingerprints re-derive, and the
@@ -376,8 +379,6 @@ class Executor:
         across the re-cost boundary.  Already-running streams are NOT
         touched — in-flight members finish on the pipeline they were
         admitted with; only subsequent plans see the new prices."""
-        if calibration is None:
-            calibration = load_calibration()
         if calibration:
             self.cost_model.apply_calibration(calibration)
         # cardinality feedback (the PR-7 leftover): per-(table, column)
@@ -1178,7 +1179,7 @@ class Executor:
         # step commits numpy operands on call through the C++ conversion
         # path, several times cheaper than a python device_put round
         # trip; cached morsels keep the put so reuse stays transfer-free
-        direct = not cache_ok and len(jax.devices()) == 1
+        direct = not cache_ok and self.mesh.size == 1
         if direct or not data:
             staged = data
         else:

@@ -37,7 +37,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.columnar import engine
@@ -420,7 +420,7 @@ def compile_pipeline(splan: StreamPlan, rows: int, agg_dtype, *,
             step, mesh=shard.mesh,
             in_specs=(P(), P(), P()) + (P(),) * n_build
             + (P(axis),) * len(splan.stream_cols),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
     donate = (1,) if jax.default_backend() != "cpu" else ()
     return CompiledPipeline(
         splan.base_scan.table, splan.stream_cols, breakers, rows,
@@ -543,7 +543,7 @@ def compile_project_pipeline(pplan: ProjectStreamPlan, rows: int, *,
             step, mesh=shard.mesh,
             in_specs=(P(), P()) + (P(),) * n_build
             + (P(axis),) * len(pplan.stream_cols),
-            out_specs=(P(axis), P(axis)), check_rep=False)
+            out_specs=(P(axis), P(axis)), check_vma=False)
     return CompiledProject(
         pplan.base_scan.table, pplan.stream_cols, breakers, rows,
         pplan.out_cols, jax.jit(raw), raw,

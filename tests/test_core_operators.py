@@ -1,4 +1,5 @@
 """Scale-out operators + columnar engine + channel planner."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -47,6 +48,19 @@ def test_select_distributed(host_mesh, rng):
     assert int(counts.sum()) == int(exp.sum())
     got = np.asarray(idx)
     np.testing.assert_array_equal(np.sort(got[got >= 0]), np.nonzero(exp)[0])
+
+
+def test_select_range_on_explicit_mesh_runs_outside_mesh_context(rng):
+    """``jax.make_mesh`` builds Explicit axes.  A column placed on one
+    carries the axis in its type, and the eager selection's compaction
+    (``jnp.nonzero``) then refused it outside a mesh context.  Placement
+    goes through the channel plan's Auto-axis mesh, so it runs."""
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    v = rng.integers(0, 1000, size=4096).astype(np.int32)
+    t = Table.from_arrays("t", {"v": v}).place(plan(mesh, "model"))
+    sel = engine.select_range(t, "v", 100, 200)
+    np.testing.assert_array_equal(np.asarray(sel.column("idx")),
+                                  np.flatnonzero((v >= 100) & (v <= 200)))
 
 
 @settings(max_examples=8, deadline=None)

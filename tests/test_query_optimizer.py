@@ -1,4 +1,6 @@
 """Optimizer rewrites + bandwidth cost model unit tests."""
+import json
+
 import numpy as np
 import pytest
 
@@ -6,7 +8,8 @@ from repro.columnar.table import Table
 from repro.core.join import HT_CAPACITY
 from repro.query import (
     Aggregate, Catalog, CostModel, Filter, FilterProject, Join, Project, Q,
-    Scan, column_placements, estimate_rows, optimize, plan_physical,
+    Scan, column_placements, estimate_rows, load_calibration, optimize,
+    plan_physical,
 )
 from repro.query.optimize import (
     choose_build_side, fuse_filter_project, prune_columns, push_down_filters,
@@ -190,6 +193,27 @@ def test_fpga_hardware_model_prices_alternatives():
     assert cong == pytest.approx(14.0, rel=0.05)
     assert model.stream_cost(1 << 26, impl="xla", placement="partitioned") \
         < model.stream_cost(1 << 26, impl="xla", placement="congested")
+
+
+def test_load_calibration_refuses_another_device(tmp_path):
+    """A calibration prices only the device it was measured on: a CPU
+    file is refused for a TPU, and a recorded device kind must match."""
+    path = tmp_path / "calibration.json"
+    path.write_text(json.dumps({"backend": "cpu", "backends": {}}))
+    with pytest.raises(ValueError, match="backend 'cpu'"):
+        load_calibration(str(path), backend="tpu")
+    assert load_calibration(str(path), backend="cpu")["backend"] == "cpu"
+    path.write_text(json.dumps({"backend": "tpu", "device_kind": "TPU v4",
+                                "backends": {}}))
+    with pytest.raises(ValueError, match="TPU v4"):
+        load_calibration(str(path), backend="tpu",
+                         device_kind="TPU v5 lite")
+
+
+def test_peak_rates_come_from_the_device_kind():
+    assert CostModel(1, device_kind="TPU v5 lite").peaks.hbm_gbps == 819.0
+    with pytest.raises(ValueError, match="no peak rates"):
+        CostModel(1, device_kind="TPU v0")
 
 
 def _walk(node):

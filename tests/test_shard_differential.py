@@ -6,6 +6,8 @@ sums) and every materialized row order (join pair lists are canonicalized
 to probe-row-major) is compared against the classic 1-device executor.
 The degenerate mesh=1 executor must be byte-for-byte the old one — same
 fingerprints, same compiled-plan cache keys, same EXPLAIN output."""
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ import pytest
 from repro.columnar.table import Column, Table
 from repro.core import channels
 from repro.core import join as join_core
+from repro.distributed import sharding as shardlib
 from repro.query import logical as L
 from repro.query.cost import (
     ColumnStats, CostModel, SEL_CORRECTION_CLAMP, TableStats,
@@ -223,6 +226,29 @@ def test_shuffle_broadcast_crossover_follows_cost_model():
         seen.add(j.shard_strategy)
     # the sweep must actually cross — both strategies win somewhere
     assert seen == {"broadcast", "shuffle"}
+
+
+def test_shuffle_join_is_one_compiled_program(rng, caplog):
+    """Run eagerly, a ``shard_map`` dispatches every op of its body as a
+    program of its own, compiled anew on each call: hundreds of compiles
+    per shuffle join.  The shuffle join is one jitted program, so a
+    repeat call with the same shapes compiles nothing, and its pairs are
+    the exact join."""
+    s = rng.integers(0, 1024, 300).astype(np.int32)
+    l = rng.integers(0, 1024, 1000).astype(np.int32)
+    args = (jnp.asarray(s), jnp.asarray(l), shardlib.ShardLayout(1))
+    join_core.join_shuffle_multi(*args)
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        caplog.clear()
+        l_idx, s_idx, totals, pair_over, (_, _, shuf_over) = \
+            join_core.join_shuffle_multi(*args)
+    assert not [r for r in caplog.records if "Compiling" in r.getMessage()]
+    assert not bool(shuf_over) and not bool(jnp.any(pair_over))
+    valid = np.asarray(l_idx) >= 0
+    got = sorted(zip(np.asarray(l_idx)[valid], np.asarray(s_idx)[valid]))
+    li, si = np.nonzero(l[:, None] == s[None, :])
+    assert got == sorted(zip(li, si))
+    assert int(jnp.sum(totals)) == li.size
 
 
 def test_mesh1_never_prices_shard_strategies():

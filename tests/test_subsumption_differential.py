@@ -103,7 +103,10 @@ def test_warm_narrower_range_bit_identical(seed, dist, lo_w, width_w,
     warm = warm_ex.execute(_proj(lo_n, hi_n)).value
     _assert_tables_equal(oracle, cold)
     _assert_tables_equal(oracle, warm)
-    want_hit = _expected_refine(warm_ex, int(seeded.value.shape[0]))
+    # the wide range asked again is answered whole by the result cache,
+    # before any subsumption is tried
+    want_hit = (lo_n, hi_n) != (lo_w, hi_w) \
+        and _expected_refine(warm_ex, int(seeded.value.shape[0]))
     assert (warm_ex.subsumption_hits == 1) == want_hit, \
         (warm_ex.subsumption_hits, int(seeded.value.shape[0]))
 
