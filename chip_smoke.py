@@ -41,11 +41,15 @@ GLM_EPOCHS = 2
 MINIBATCH = 16                 # TrainGLM's minibatch
 CACHE_BYTES = 256 << 20        # semantic cache budget of the served phases
 # float32 SGD on the chip runs its dots at the TPU's default matmul
-# precision (bfloat16 products, float32 sums): the tolerances allow a
-# few bfloat16 ulps (2^-8) on O(1) values
-LOSS_RTOL = 1e-2
-SCORE_ATOL = 1e-2
-WEIGHT_RTOL = 5e-2             # relative to the weight vector's norm
+# precision (bfloat16 products, float32 sums), which moved weights,
+# losses and scores by 2e-4 to 4e-4 on a v5e: the limits sit about 10x
+# above that.  The grid's slow model (lr 1e-3) is still far from
+# converged after GLM_EPOCHS, so a skipped epoch or half the rows moves
+# its weights by ~0.19 and its loss by ~0.08, and a lost l2 term moves
+# the l2 models' weights by ~3: each fails these limits
+LOSS_RTOL = 3e-3
+SCORE_ATOL = 4e-3
+WEIGHT_RTOL = 3e-3             # relative to the weight vector's norm
 INT32_MAX = 2 ** 31 - 1
 
 
@@ -324,7 +328,7 @@ def one_chip(data: dict, ph: Phases) -> None:
         glm = data["glm"]
         feats = [f"f{i}" for i in range(GLM_FEATURES)]
         grid = [HyperParams(0.1, 0.0), HyperParams(0.05, 1e-2),
-                HyperParams(0.2, 1e-3)]
+                HyperParams(1e-3, 1e-3)]
         train = Q.scan("glm").train_glm(feats, "y", grid, epochs=GLM_EPOCHS)
         a = np.stack([glm[f] for f in feats], axis=1)
         t0 = time.perf_counter()
@@ -452,14 +456,15 @@ def four_chips(data: dict, ph: Phases, n: int) -> None:
             strategies[label] = set(
                 re.findall(r"strategy=(\w+)", exn.explain(q)))
             print(f"  strategies {label}: {sorted(strategies[label])}")
-            # the fused step broadcasts every build; the eager lowering
-            # runs the planned strategy (a shuffle repartitions both
-            # sides).  It traces anew per call and has the one-device
-            # batch answer above to match: one call, sharded only
+            # the fused step broadcasts every build: it runs the small
+            # build's planned strategy.  The shuffle runs only in the
+            # eager lowering, which repartitions both sides; it has the
+            # one-device batch answer above to match: one call, sharded
             both(q, "batch", want, label, warm=False)
-            r = timed(exn, q, f"{label} eager {n} shards", mode="eager",
-                      warm=False, explain=False)
-            expect(f"{label} eager {n} shards", r.value, want)
+            if "shuffle" in strategies[label]:
+                r = timed(exn, q, f"{label} eager {n} shards",
+                          mode="eager", warm=False, explain=False)
+                expect(f"{label} eager {n} shards", r.value, want)
     expect("small build strategy", strategies["small_build_join"],
            {"broadcast"})
     expect("large build strategy", strategies["large_build_join"],

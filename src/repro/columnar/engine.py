@@ -25,6 +25,11 @@ from repro.kernels.join import ref as join_ref
 from repro.kernels.sgd import ref as sgd_ref
 
 
+# rows per engine block of the eager selection (``Executor._filter_table``
+# hands the kernel only base columns that split into whole blocks)
+SELECT_BLOCK = 1024
+
+
 def compact_positions(valid: jax.Array, n: int) -> jax.Array:
     """Positions of the first ``n`` True entries, ascending.
 
@@ -41,10 +46,11 @@ def scan(table: Table, columns: Sequence[str]) -> Table:
 
 
 def select_range(table: Table, column: str, lo: int, hi: int, *,
-                 impl: str = "xla", block: int = 1024) -> Table:
+                 impl: str = "xla", block: int = SELECT_BLOCK) -> Table:
     """Range selection -> materialized index column (with count).
     ``block`` halves itself until the per-engine shard tiles evenly, so
-    the same call works on a 1-engine and an 8-engine mesh."""
+    the same call works on a 1-engine and an 8-engine mesh.  The kernel
+    (``impl="pallas"``) needs blocks of whole 128-lane rows."""
     assert table.plan is not None, "place() the table first"
     n_eng = table.plan.n_engines
     if table.plan.placement != "partitioned" or \
@@ -61,10 +67,10 @@ def select_range(table: Table, column: str, lo: int, hi: int, *,
         return Table(f"{table.name}.sel", {"idx": Column(idx, "idx")})
     while block > 1 and table.num_rows % (n_eng * block) != 0:
         block //= 2
-    if block % 128:
-        # the kernel's blocks tile the TPU's 128 lanes; a row count that
-        # only halves to a smaller block takes the XLA mask (same answer)
-        impl = "xla"
+    if impl == "pallas" and block % 128:
+        raise ValueError(
+            f"the selection kernel needs 128-lane blocks: {table.num_rows} "
+            f"rows over {n_eng} engines split into {block}-row blocks")
     idx, counts = sel_core.select_distributed(
         table.column(column), lo, hi, table.plan, block=block, impl=impl)
     flat = idx.reshape(-1)

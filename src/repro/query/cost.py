@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 
+from repro.columnar.engine import SELECT_BLOCK
 from repro.core.channels import (
     device_peaks, fpga_bandwidth_model, tpu_bandwidth_model,
 )
@@ -74,29 +75,27 @@ TIERS = ("device", "host", "disk")
 PALLAS_OPS = frozenset({"filter", "filter_project"})
 
 
-def load_calibration(path: str, *, backend: Optional[str] = None,
-                     device_kind: Optional[str] = None) -> dict:
+def load_calibration(path: str) -> dict:
     """Measured stream efficiencies / call overheads as written by
     ``benchmarks/calibrate.py``.  A calibration prices only the device it
     was measured on, so a file whose ``backend`` — or ``device_kind``,
-    where it records one — differs from ``backend``/``device_kind``
-    (default: the running device's) is refused with ``ValueError``."""
+    where it records one — differs from the running device's is refused
+    with ``ValueError``."""
     with open(path) as f:
         data = json.load(f)
     if not isinstance(data, dict) or "backends" not in data:
         raise ValueError(f"{path} is not a calibration file")
     dev = jax.devices()[0]
-    want = backend or dev.platform
-    if data.get("backend") != want:
+    if data.get("backend") != dev.platform:
         raise ValueError(
             f"{path} was measured on backend {data.get('backend')!r}, "
-            f"not on {want!r}: recalibrate on this device "
+            f"not on {dev.platform!r}: recalibrate on this device "
             "(benchmarks/calibrate.py) or pass no calibration")
-    kind = device_kind or dev.device_kind
-    if "device_kind" in data and data["device_kind"] != kind:
+    if "device_kind" in data and data["device_kind"] != dev.device_kind:
         raise ValueError(
             f"{path} was measured on a {data['device_kind']!r}, not on a "
-            f"{kind!r}: recalibrate on this device or pass no calibration")
+            f"{dev.device_kind!r}: recalibrate on this device or pass no "
+            "calibration")
     return data
 
 
@@ -240,9 +239,8 @@ def expected_chain_length(node: L.Node, column: str,
 class CostModel:
     """Prices one physical operator alternative at a time.
 
-    ``hardware="tpu"`` uses the mesh/ICI analogue with the peaks of
-    ``device_kind`` (default: the running device's; an unknown kind
-    raises); ``hardware="fpga"`` prices with the paper's calibrated AD9H7
+    ``hardware="tpu"`` uses the mesh/ICI analogue with the running
+    device's peaks (an unknown device kind raises); ``hardware="fpga"`` prices with the paper's calibrated AD9H7
     channel model (32 ports, 256 MiB separation when partitioned, 0 when
     congested) — the same decision procedure on either bandwidth curve.
     """
@@ -250,11 +248,9 @@ class CostModel:
     def __init__(self, n_engines: int, *, n_shards: int = 1,
                  hardware: str = "tpu",
                  allow_pallas: Optional[bool] = None,
-                 calibration: Optional[dict] = None,
-                 device_kind: Optional[str] = None):
+                 calibration: Optional[dict] = None):
         self.n_engines = n_engines
-        self.peaks = device_peaks(device_kind
-                                  or jax.devices()[0].device_kind)
+        self.peaks = device_peaks(jax.devices()[0].device_kind)
         # explicit shard_map striping width (device = pseudo-channel);
         # 1 = the classic single-pipeline plans, byte-for-byte unchanged
         self.n_shards = max(int(n_shards), 1)
@@ -613,12 +609,17 @@ class PhysNode:
 
 def _choose(model: CostModel, op: str, n_bytes: float,
             placements: Tuple[str, ...], *, n_passes: int = 1,
-            flops: float = 0.0):
+            flops: float = 0.0,
+            pallas_placements: Optional[Tuple[str, ...]] = None):
     """argmin over impl x placement for ``op``; returns (impl, placement,
-    cost, alts)."""
+    cost, alts).  ``pallas_placements``, where given, are the only
+    placements on which the kernel would run."""
     alts = {}
     for impl in model.impls(op):
         for pl in placements:
+            if impl == "pallas" and pallas_placements is not None \
+                    and pl not in pallas_placements:
+                continue
             alts[f"{impl}/{pl}"] = model.stream_cost(
                 n_bytes, impl=impl, placement=pl, n_passes=n_passes,
                 flops=flops)
@@ -678,7 +679,18 @@ def plan_physical(node: L.Node, stats: Dict[str, TableStats],
             else _stream_placements(model)
         op = "filter_project" if isinstance(node, L.FilterProject) \
             else "filter"
-        impl, pl, cost, alts = _choose(model, op, n_bytes, placements)
+        # the executor runs the selection kernel only on a base column it
+        # partitions into whole SELECT_BLOCK blocks per engine
+        # (Executor._filter_table); every other selection takes the XLA
+        # mask, so only there is the kernel an alternative
+        engines = {"partitioned": model.n_engines, "sharded": model.n_shards}
+        kernel_pls = tuple(
+            p for p in placements
+            if role != "build" and isinstance(node.child, L.Scan)
+            and p in engines
+            and in_rows % (engines[p] * SELECT_BLOCK) == 0)
+        impl, pl, cost, alts = _choose(model, op, n_bytes, placements,
+                                       pallas_placements=kernel_pls)
         return PhysNode(op, node, impl, pl, 1, rows, cost,
                         model.bandwidth_gbps(pl), alts, (child,),
                         n_bytes=n_bytes)

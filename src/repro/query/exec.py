@@ -1433,7 +1433,8 @@ class Executor:
 
     def _filter_table(self, t: Table, column: str, lo: int, hi: int,
                       keep: Tuple[str, ...], *, impl: str = "xla",
-                      block: int = 1024, cache_ok: bool = True) -> Table:
+                      block: int = engine.SELECT_BLOCK,
+                      cache_ok: bool = True) -> Table:
         # selection bitmaps over BASE tables are cacheable: the compacted
         # index column is the selection's whole cost, and the key embeds
         # the table version so a mutated column can never replay.

@@ -1,10 +1,12 @@
 """Optimizer rewrites + bandwidth cost model unit tests."""
 import json
 
+import jax
 import numpy as np
 import pytest
 
 from repro.columnar.table import Table
+from repro.core.channels import device_peaks
 from repro.core.join import HT_CAPACITY
 from repro.query import (
     Aggregate, Catalog, CostModel, Filter, FilterProject, Join, Project, Q,
@@ -185,6 +187,31 @@ def test_impl_crossover_xla_small_pallas_large():
     assert big < big_x            # streaming efficiency dominates large
 
 
+def test_filter_kernel_priced_only_where_the_executor_runs_it(catalog):
+    """explain names what runs: the eager executor hands the selection
+    kernel only base columns partitioned into whole SELECT_BLOCK blocks
+    per engine, and takes the XLA mask everywhere else."""
+    model = CostModel(4, allow_pallas=True)
+
+    def pallas_alts(p):
+        return sorted(a for a in p.alternatives if a.startswith("pallas"))
+
+    # big: 4096 rows = 4 engines x 1024
+    big = plan_physical(Q.scan("big").filter("v", 0, 9).node,
+                        catalog.stats, model)
+    assert pallas_alts(big) == ["pallas/partitioned"]
+    # small: 512 rows do not fill one block per engine
+    small = plan_physical(Q.scan("small").filter("x", 0, 9).node,
+                          catalog.stats, model)
+    assert small.impl == "xla" and pallas_alts(small) == []
+    # a filtered build side is replicated: the executor masks it
+    join = plan_physical(Q.scan("small").join(
+        Q.scan("big").filter("v", 0, 9), on="k").node, catalog.stats, model)
+    (build_filter,) = [p for p in _walk_phys(join) if p.op == "filter"]
+    assert build_filter.placement == "replicated"
+    assert pallas_alts(build_filter) == []
+
+
 def test_fpga_hardware_model_prices_alternatives():
     model = CostModel(32, hardware="fpga", allow_pallas=False)
     part = model.bandwidth_gbps("partitioned")
@@ -196,24 +223,28 @@ def test_fpga_hardware_model_prices_alternatives():
 
 
 def test_load_calibration_refuses_another_device(tmp_path):
-    """A calibration prices only the device it was measured on: a CPU
-    file is refused for a TPU, and a recorded device kind must match."""
+    """A calibration prices only the device it was measured on: a file
+    from another backend is refused, and a recorded device kind must
+    match the running device's."""
+    dev = jax.devices()[0]
+    other = "tpu" if dev.platform != "tpu" else "cpu"
     path = tmp_path / "calibration.json"
-    path.write_text(json.dumps({"backend": "cpu", "backends": {}}))
-    with pytest.raises(ValueError, match="backend 'cpu'"):
-        load_calibration(str(path), backend="tpu")
-    assert load_calibration(str(path), backend="cpu")["backend"] == "cpu"
-    path.write_text(json.dumps({"backend": "tpu", "device_kind": "TPU v4",
-                                "backends": {}}))
+    path.write_text(json.dumps({"backend": other, "backends": {}}))
+    with pytest.raises(ValueError, match=f"backend '{other}'"):
+        load_calibration(str(path))
+    path.write_text(json.dumps({"backend": dev.platform, "backends": {}}))
+    assert load_calibration(str(path))["backend"] == dev.platform
+    path.write_text(json.dumps({"backend": dev.platform,
+                                "device_kind": "TPU v4", "backends": {}}))
     with pytest.raises(ValueError, match="TPU v4"):
-        load_calibration(str(path), backend="tpu",
-                         device_kind="TPU v5 lite")
+        load_calibration(str(path))
 
 
 def test_peak_rates_come_from_the_device_kind():
-    assert CostModel(1, device_kind="TPU v5 lite").peaks.hbm_gbps == 819.0
+    assert device_peaks("TPU v5 lite").hbm_gbps == 819.0
+    assert CostModel(1).peaks == device_peaks(jax.devices()[0].device_kind)
     with pytest.raises(ValueError, match="no peak rates"):
-        CostModel(1, device_kind="TPU v0")
+        device_peaks("TPU v0")
 
 
 def _walk(node):

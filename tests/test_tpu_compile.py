@@ -84,7 +84,7 @@ def test_planner_offers_pallas_only_where_a_kernel_compiles():
     """The join probes' kernels are refused by the TPU compiler (1-D
     in-kernel gather), so joins price XLA alone even where pallas is
     allowed; filters keep the kernel compiled above."""
-    model = CostModel(1, allow_pallas=True, device_kind="TPU v5 lite")
+    model = CostModel(1, allow_pallas=True)
     assert model.impls("filter") == ("xla", "pallas")
     for op in ("join", "join_multi", "scan", "aggregate", "train_glm"):
         assert model.impls(op) == ("xla",), op
@@ -116,7 +116,7 @@ def test_fused_join_step_fits_one_chip(one_chip, dim, on, value, n_build):
     stats = _stats()
     q = (L.Q.scan("lineitem").join(L.Q.scan(dim), on=on)
          .filter("quantity", 30, 49).sum(value))
-    opt = optimize(q.node, stats, CostModel(1, device_kind="TPU v5 lite"))
+    opt = optimize(q.node, stats, CostModel(1))
     splan = pl.analyze(opt, stats)
     assert splan is not None, "the plan must lower onto the fused pipeline"
     cp = pl.compile_pipeline(splan, ROWS, jnp.int32)
