@@ -19,8 +19,6 @@ def main() -> None:
     from benchmarks.bench_cache import cache_figures, subsumption_smoke
     from benchmarks.bench_join_duplicates import join_duplicates
     from benchmarks.bench_glm import glm_smoke
-    from benchmarks.bench_observability import (
-        observability_figures, observability_smoke)
     from benchmarks.bench_qos import qos_figures, qos_smoke
     from benchmarks.bench_shard import shard_figures, shard_smoke
     from benchmarks.bench_tiering import tiering_smoke
@@ -37,15 +35,14 @@ def main() -> None:
     # join_duplicates / cache_figures run full-scale only: smoke mode
     # keeps the two fast figures, and the bench_*.py --smoke entry points
     # cover the smoke case
-    fns = ALL + [join_duplicates, cache_figures, observability_figures,
-                 qos_figures, shard_figures]
+    fns = ALL + [join_duplicates, cache_figures, qos_figures,
+                 shard_figures]
     if smoke:
         # subsumption_smoke exercises the refine path + shared cache at
         # smoke scale without clobbering the committed BENCH_cache.json;
-        # observability_smoke writes BENCH_observability.json + the
-        # Chrome trace artifact on every smoke run; qos_smoke hard-gates
-        # the adaptive-replan correctness invariants; shard_smoke
-        # re-execs itself under 8 forced host devices and hard-gates
+        # qos_smoke hard-gates the adaptive-replan correctness
+        # invariants; shard_smoke re-execs itself under 8 forced host
+        # devices and hard-gates
         # scaling monotonicity, the shuffle/broadcast crossover, and
         # sharded-vs-oracle bit-identity; tiering_smoke hard-gates the
         # over-capacity spill sweep, the kill-and-restart warm start
@@ -55,8 +52,8 @@ def main() -> None:
         # replication trade
         fns = [fn for fn in ALL if fn.__name__ in
                ("fig2_bandwidth", "tab3_roofline")] + \
-              [subsumption_smoke, observability_smoke, qos_smoke,
-               shard_smoke, tiering_smoke, glm_smoke]
+              [subsumption_smoke, qos_smoke, shard_smoke, tiering_smoke,
+               glm_smoke]
     if only:
         fns = [fn for fn in fns if only in fn.__name__]
 

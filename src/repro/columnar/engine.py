@@ -311,7 +311,7 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
                      grid, plan: ChannelPlan, *, kind: str = "logreg",
                      epochs: int = 5, minibatch: int = 16,
                      morsel_rows: Optional[int] = None,
-                     on_morsel=None):
+                     on_morsel=None, telemetry, metrics=None):
     """Morsel-streamed hyper-parameter search: each epoch streams the
     morsels in table order with the K models' parameters as the carry, so
     the minibatch update sequence — and therefore the trained weights —
@@ -329,7 +329,16 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     Morsels come from ``Table.morsel``, so host/disk-resident (spilled)
     columns stream tier-aware: the numpy slice + H2D promotion happens
     per morsel and the training set never has to fit on device whole.
-    ``on_morsel(n_bytes, seconds, tier)`` observes each promotion."""
+    ``on_morsel(n_bytes, seconds, tier)`` observes each promotion, and
+    fences each morsel's staging to time it.
+
+    Spans of ``telemetry`` (any object whose ``span(name, **attrs)`` is
+    a context manager): ``trainer.stage`` per morsel staged (slice, casts,
+    stack, ``device_put``), ``trainer.epoch_step`` per morsel and epoch and
+    ``trainer.loss_step`` per morsel, each around the step's dispatch,
+    including any trace or compile of its program.  Each trace of either
+    step opens a ``trainer.trace`` span and counts one ``trainer.traces``
+    in ``metrics``."""
     m = table.num_rows
     if morsel_rows is None:
         morsel_rows = m
@@ -343,26 +352,37 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     xs = jnp.zeros((k, len(features)), jnp.float32)
     rep = NamedSharding(plan.mesh, P())      # dataset replication (Fig. 10a)
 
+    def traced():
+        # runs in the steps' Python bodies, so once per trace; the span
+        # marks each trace in a profiler trace too
+        with telemetry.span("trainer.trace"):
+            if metrics is not None:
+                metrics.inc("trainer.traces")
+
     def morsel_arrays(i):
-        t0 = time.perf_counter()
-        data, n_valid = table.morsel(spec, i, cols)
-        # Table.morsel pads the ragged tail to spec.rows; keep only up to
-        # the next minibatch multiple past the valid rows
-        rows_pad = -(-n_valid // minibatch) * minibatch
-        a = jnp.stack([jnp.asarray(data[f][:rows_pad]).astype(jnp.float32)
-                       for f in features], axis=1)
-        b = jnp.asarray(data[label][:rows_pad]).astype(jnp.float32)
-        a, b = jax.device_put(a, rep), jax.device_put(b, rep)
-        if on_morsel is not None:
-            jax.block_until_ready(b)
-            tiers = {table.column_tier(c) for c in cols}
-            worst = "disk" if "disk" in tiers else \
-                ("host" if "host" in tiers else "device")
-            on_morsel(a.nbytes + b.nbytes, time.perf_counter() - t0, worst)
-        return a, b, n_valid
+        with telemetry.span("trainer.stage", morsel=i):
+            t0 = time.perf_counter()
+            data, n_valid = table.morsel(spec, i, cols)
+            # Table.morsel pads the ragged tail to spec.rows; keep only up
+            # to the next minibatch multiple past the valid rows
+            rows_pad = -(-n_valid // minibatch) * minibatch
+            a = jnp.stack([jnp.asarray(data[f][:rows_pad])
+                           .astype(jnp.float32) for f in features], axis=1)
+            b = jnp.asarray(data[label][:rows_pad]).astype(jnp.float32)
+            a, b = jax.device_put(a, rep), jax.device_put(b, rep)
+            if on_morsel is not None:
+                jax.block_until_ready(b)
+                tiers = {table.column_tier(c) for c in cols}
+                worst = "disk" if "disk" in tiers else \
+                    ("host" if "host" in tiers else "device")
+                on_morsel(a.nbytes + b.nbytes, time.perf_counter() - t0,
+                          worst)
+            return a, b, n_valid
 
     @jax.jit
     def epoch_step(xs, a_m, b_m):
+        traced()
+
         def one(x, lr, l2):
             return sgd_ref.sgd_ref(a_m, b_m, x, lr=lr, l2=l2,
                                    minibatch=minibatch, epochs=1, kind=kind)
@@ -370,6 +390,7 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
 
     @jax.jit
     def loss_step(acc, a_m, b_m, n_valid, xs):
+        traced()
         valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
 
         def rowsum(x):
@@ -384,13 +405,15 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
             return jnp.sum(j * valid)
         return acc + jax.vmap(rowsum)(xs)
 
-    for _ in range(epochs):
+    for e in range(epochs):
         for i in range(spec.n_morsels):
             a_m, b_m, _ = morsel_arrays(i)
-            xs = epoch_step(xs, a_m, b_m)
+            with telemetry.span("trainer.epoch_step", morsel=i, epoch=e):
+                xs = epoch_step(xs, a_m, b_m)
     acc = jnp.zeros((k,), jnp.float32)
     for i in range(spec.n_morsels):
         a_m, b_m, n_valid = morsel_arrays(i)
-        acc = loss_step(acc, a_m, b_m, jnp.int32(n_valid), xs)
+        with telemetry.span("trainer.loss_step", morsel=i):
+            acc = loss_step(acc, a_m, b_m, jnp.int32(n_valid), xs)
     losses = acc / m + l2s * jnp.sum(jnp.square(xs), axis=1)
     return xs, losses

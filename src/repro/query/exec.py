@@ -638,26 +638,26 @@ class Executor:
         arrays = [self.placed(t, c, p) for t, c, p in specs]
         builds = self._breaker_arrays(splan.breakers)
         lits = jnp.asarray(L.literals(node), jnp.int32)
-        if not self.tel.enabled:
-            carry = cp.step(lits, cp.init_carry(), jnp.int32(cp.rows),
-                            *builds, *arrays)
-            return cp.finalize(carry), hit
-        # fenced measurement: settle async input transfers first, then
-        # time dispatch-to-completion of the fused step — the one
-        # measurement the ledger apportions across the plan's operators
+        ledger = self.tel.ledger.enabled
         with self.tel.span("exec.run_fused", compiled_hit=hit) as sp:
-            jax.block_until_ready(arrays)
-            jax.block_until_ready(builds)
-            t0 = time.perf_counter()
+            if ledger:
+                # fenced measurement: settle async input transfers first,
+                # then time dispatch-to-completion of the fused step — the
+                # one measurement the ledger apportions across the plan's
+                # operators
+                jax.block_until_ready(arrays)
+                jax.block_until_ready(builds)
+                t0 = time.perf_counter()
             carry = cp.step(lits, cp.init_carry(), jnp.int32(cp.rows),
                             *builds, *arrays)
-            jax.block_until_ready(carry)
-            dt = time.perf_counter() - t0
-            moved = sum(a.nbytes for a in arrays) \
-                + sum(b.nbytes for b in builds)
-            sp.set(measured_s=dt, measured_bytes=moved)
-            self.tel.ledger.record_plan(phys, dt, moved, mode="fused",
-                                        shards=self.n_shards)
+            if ledger:
+                jax.block_until_ready(carry)
+                dt = time.perf_counter() - t0
+                moved = sum(a.nbytes for a in arrays) \
+                    + sum(b.nbytes for b in builds)
+                sp.set(measured_s=dt, measured_bytes=moved)
+                self.tel.ledger.record_plan(phys, dt, moved, mode="fused",
+                                            shards=self.n_shards)
             return cp.finalize(carry), hit
 
     def _route_to_refine(self, node: L.Node, splan: pl.StreamPlan) -> bool:
@@ -908,25 +908,26 @@ class Executor:
         get = lambda i: self._stream_morsel(table, cp.stream_cols,   # noqa: E731
                                             spec, i, cache_ok,
                                             promote=promote)
-        if not self.tel.enabled:
-            carry = pl.drive(cp, spec.n_morsels, get, builds, lits,
-                             prefetch=self.overlap_transfers)
-            return cp.finalize(carry), hit
+        ledger = self.tel.ledger.enabled
         with self.tel.span("exec.run_stream", n_morsels=spec.n_morsels,
                            morsel_rows=spec.rows, compiled_hit=hit) as sp:
-            jax.block_until_ready(builds)
-            t0 = time.perf_counter()
+            if ledger:
+                jax.block_until_ready(builds)
+                t0 = time.perf_counter()
             carry = pl.drive(cp, spec.n_morsels, get, builds, lits,
                              prefetch=self.overlap_transfers,
-                             telemetry=self.tel, metrics=self.metrics)
-            jax.block_until_ready(carry)
-            dt = time.perf_counter() - t0
-            moved = self.catalog.stats[table].num_rows * 4 \
-                * len(cp.stream_cols) + sum(b.nbytes for b in builds)
-            sp.set(measured_s=dt, measured_bytes=moved)
-            self.tel.ledger.record_plan(phys, dt, moved, mode="stream",
-                                        shards=self.n_shards)
-            self._record_promotions(promote, mode="stream")
+                             telemetry=self.tel,
+                             metrics=self.metrics if self.tel.enabled
+                             else None)
+            if ledger:
+                jax.block_until_ready(carry)
+                dt = time.perf_counter() - t0
+                moved = self.catalog.stats[table].num_rows * 4 \
+                    * len(cp.stream_cols) + sum(b.nbytes for b in builds)
+                sp.set(measured_s=dt, measured_bytes=moved)
+                self.tel.ledger.record_plan(phys, dt, moved, mode="stream",
+                                            shards=self.n_shards)
+                self._record_promotions(promote, mode="stream")
             return cp.finalize(carry), hit
 
     def _clamp_spec(self, spec: MorselSpec, n_cols: int,
@@ -990,7 +991,7 @@ class Executor:
         value = Table("proj", {
             c: Column(jnp.asarray(np.concatenate([ch[c] for ch in chunks])),
                       c) for c in cpj.out_cols})
-        if self.tel.enabled:
+        if self.tel.ledger.enabled:
             dt = time.perf_counter() - t0
             moved = self.catalog.stats[table].num_rows * BYTES_PER_VALUE \
                 * n_cols + sum(b.nbytes for b in builds)
@@ -1028,11 +1029,7 @@ class Executor:
                 MorselSpec(source.num_rows, target), n_cols, cap).rows
         cplan = self.plans.get(phys.placement if phys else "partitioned",
                                self.plans["partitioned"])
-        if not self.tel.enabled:
-            return engine.train_glm_stream(
-                source, list(node.features), node.label, list(node.grid),
-                cplan, kind=node.kind, epochs=node.epochs,
-                morsel_rows=target)
+        ledger = self.tel.ledger.enabled
         promote = {"host": [0, 0.0], "disk": [0, 0.0]}
 
         def on_morsel(n_bytes, seconds, tier):
@@ -1049,18 +1046,22 @@ class Executor:
             value = engine.train_glm_stream(
                 source, list(node.features), node.label, list(node.grid),
                 cplan, kind=node.kind, epochs=node.epochs,
-                morsel_rows=target, on_morsel=on_morsel)
-            jax.block_until_ready(value)
-            dt = time.perf_counter() - t0
-            # mirror the cost formula with actual cardinality (the same
-            # convention as _eager_measured_bytes) so ledger drift
-            # isolates estimation error from bandwidth-model error
-            moved = source.num_rows * BYTES_PER_VALUE * n_cols \
-                * node.epochs * len(node.grid)
-            sp.set(measured_s=dt, measured_bytes=moved)
-            self.tel.ledger.record_plan(phys, dt, moved, mode="stream",
-                                        shards=self.n_shards)
-            self._record_promotions(promote, mode="stream")
+                morsel_rows=target,
+                on_morsel=on_morsel if ledger else None,
+                telemetry=self.tel, metrics=self.metrics)
+            if ledger:
+                jax.block_until_ready(value)
+                dt = time.perf_counter() - t0
+                # mirror the cost formula with actual cardinality (the
+                # same convention as _eager_measured_bytes) so ledger
+                # drift isolates estimation error from bandwidth-model
+                # error
+                moved = source.num_rows * BYTES_PER_VALUE * n_cols \
+                    * node.epochs * len(node.grid)
+                sp.set(measured_s=dt, measured_bytes=moved)
+                self.tel.ledger.record_plan(phys, dt, moved, mode="stream",
+                                            shards=self.n_shards)
+                self._record_promotions(promote, mode="stream")
             return value
 
     def morsel_spec(self, table: str, target: Optional[int] = None,
@@ -1168,8 +1169,8 @@ class Executor:
         promoted = promote is not None \
             and any(t != "device" for t in tiers.values())
         # timing needs a fence, which would serialize the prefetch
-        # overlap — only pay it when telemetry wants the ledger rows
-        timing = promoted and self.tel.enabled
+        # overlap — only pay it when the ledger wants the rows
+        timing = promoted and self.tel.ledger.enabled
         t0 = time.perf_counter() if timing else 0.0
         data = tab.morsel(spec, i, missing)[0] if missing else {}
         # one pytree device_put for all missing columns: the dispatch
@@ -1242,7 +1243,7 @@ class Executor:
         # and measured bytes mirror the cost model's formulas with
         # ACTUAL cardinalities — so drift isolates estimation error
         # (bytes) from bandwidth-model error (time)
-        ledger_on = self.tel.enabled and phys is not None
+        ledger_on = self.tel.ledger.enabled and phys is not None
         frames: list = []        # per live node: [child_incl_s, child_outs]
 
         def traced_eval(n):

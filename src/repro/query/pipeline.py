@@ -44,6 +44,7 @@ from repro.columnar import engine
 from repro.distributed.sharding import ShardLayout
 from repro.kernels.join import ref as join_ref
 from repro.query import logical as L
+from repro.query import telemetry as tm
 from repro.query.cost import TableStats, key_is_unique
 
 
@@ -550,23 +551,17 @@ def compile_project_pipeline(pplan: ProjectStreamPlan, rows: int, *,
         shard=shard if sharded else None)
 
 
-def _account_morsel(telemetry, metrics, i: int, t0: float, t1: float,
-                    t2: float, path: str) -> None:
-    """One morsel's split: transfer-wait (t0..t1 — blocked on staging)
-    vs compute dispatch (t1..t2).  The overlap-effectiveness numbers the
-    ISSUE asks for fall out of the two running sums: with perfect H2D
+def _account_morsel(metrics, t0: float, t1: float, t2: float) -> None:
+    """One morsel's split on the host clock: transfer-wait (t0..t1 —
+    blocked on staging) vs compute dispatch (t1..t2).  With perfect H2D
     overlap the wait term collapses toward zero."""
-    if metrics is not None:
-        metrics.inc("pipeline.morsels")
-        metrics.inc("pipeline.transfer_wait_s", t1 - t0)
-        metrics.inc("pipeline.compute_s", t2 - t1)
-        metrics.observe("pipeline.morsel_wait_s", t1 - t0)
-        metrics.observe("pipeline.morsel_step_s", t2 - t1)
-    if telemetry is not None:
-        telemetry.complete("pipeline.morsel_wait", t0, t1 - t0,
-                           morsel=i, path=path)
-        telemetry.complete("pipeline.morsel_step", t1, t2 - t1,
-                           morsel=i, path=path)
+    if metrics is None:
+        return
+    metrics.inc("pipeline.morsels")
+    metrics.inc("pipeline.transfer_wait_s", t1 - t0)
+    metrics.inc("pipeline.compute_s", t2 - t1)
+    metrics.observe("pipeline.morsel_wait_s", t1 - t0)
+    metrics.observe("pipeline.morsel_step_s", t2 - t1)
 
 
 def drive(cp: CompiledPipeline, n_morsels: int, get_morsel, build_flat,
@@ -584,12 +579,15 @@ def drive(cp: CompiledPipeline, n_morsels: int, get_morsel, build_flat,
     debugging; both orders fold morsels identically, so results are
     bit-identical either way.
 
-    ``telemetry``/``metrics`` (both optional, default off) record the
-    per-morsel transfer-wait vs compute split — the direct measurement
-    of how effective the H2D overlap actually is.  When omitted the
-    loops below run exactly the uninstrumented hot path."""
+    Each morsel's wait for its staging runs under a
+    ``pipeline.morsel_wait`` span and its step's dispatch under
+    ``pipeline.morsel_step``, spans of ``telemetry`` (the process-global
+    one when omitted).  ``metrics`` (optional) also sums the two on the
+    host clock — the direct measurement of how effective the H2D overlap
+    is."""
+    tel = telemetry if telemetry is not None else tm.get()
+    clock = time.perf_counter
     carry = cp.init_carry() if carry is None else carry
-    instrumented = telemetry is not None and telemetry.enabled
     if prefetch and n_morsels > 1:
         buf: queue.Queue = queue.Queue(maxsize=2)
         failure: list = []
@@ -620,53 +618,42 @@ def drive(cp: CompiledPipeline, n_morsels: int, get_morsel, build_flat,
         t.start()
         try:
             for i in range(n_morsels):
-                if not instrumented:
+                t0 = clock()
+                with tel.span("pipeline.morsel_wait", morsel=i,
+                              path="prefetch"):
                     item = buf.get()
-                    if item is None:
-                        break
-                    cur_arrays, n_valid = item
-                    carry = cp.step(lits, carry, n_valid, *build_flat,
-                                    *cur_arrays)
-                    continue
-                t0 = time.perf_counter()
-                item = buf.get()
                 if item is None:
                     break
                 cur_arrays, n_valid = item
-                t1 = time.perf_counter()
-                carry = cp.step(lits, carry, n_valid, *build_flat,
-                                *cur_arrays)
-                _account_morsel(telemetry, metrics, i, t0, t1,
-                                time.perf_counter(), "prefetch")
+                t1 = clock()
+                with tel.span("pipeline.morsel_step", morsel=i,
+                              path="prefetch"):
+                    carry = cp.step(lits, carry, n_valid, *build_flat,
+                                    *cur_arrays)
+                _account_morsel(metrics, t0, t1, clock())
         finally:
             stop.set()
             t.join()
         if failure:
             raise failure[0]
         return carry
-    if instrumented:
-        t0 = time.perf_counter()
+    t0 = clock()
+    with tel.span("pipeline.morsel_wait", morsel=0, path="double_buffer"):
         nxt = get_morsel(0)
-        t_stage = time.perf_counter() - t0
-        for i in range(n_morsels):
-            cur_arrays, n_valid = nxt
-            t0 = time.perf_counter()
-            if i + 1 < n_morsels:
-                nxt = get_morsel(i + 1)
-            t1 = time.perf_counter()
-            # in the double-buffered loop the NEXT morsel's staging is
-            # the serial (non-overlapped) transfer term for this step
-            carry = cp.step(lits, carry, n_valid, *build_flat,
-                            *cur_arrays)
-            _account_morsel(telemetry, metrics, i,
-                            t0 - t_stage if i == 0 else t0, t1,
-                            time.perf_counter(), "double_buffer")
-            t_stage = 0.0
-        return carry
-    nxt = get_morsel(0)
+    t_stage = clock() - t0
     for i in range(n_morsels):
         cur_arrays, n_valid = nxt
+        t0 = clock()
         if i + 1 < n_morsels:
-            nxt = get_morsel(i + 1)
-        carry = cp.step(lits, carry, n_valid, *build_flat, *cur_arrays)
+            with tel.span("pipeline.morsel_wait", morsel=i + 1,
+                          path="double_buffer"):
+                nxt = get_morsel(i + 1)
+        t1 = clock()
+        # in the double-buffered loop the NEXT morsel's staging is the
+        # serial (non-overlapped) transfer term for this step
+        with tel.span("pipeline.morsel_step", morsel=i,
+                      path="double_buffer"):
+            carry = cp.step(lits, carry, n_valid, *build_flat, *cur_arrays)
+        _account_morsel(metrics, t0 - t_stage, t1, clock())
+        t_stage = 0.0
     return carry

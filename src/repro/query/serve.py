@@ -57,6 +57,7 @@ import numpy as np
 from repro.columnar.table import Column, Table
 from repro.query import logical as L
 from repro.query import pipeline as pl
+from repro.query import telemetry as tm
 from repro.query.exec import Executor
 from repro.query.optimize import common_subplans
 
@@ -291,9 +292,9 @@ class _MorselStream:
         ex = self.server.executor
         # the serving stream's ledger feed: fence this advance and record
         # one measured slice against 1/n_morsels of each pinned plan's
-        # prediction.  Timing only when telemetry is on — the disabled
-        # path must keep its <2% overhead bound (no sync, no clock)
-        ledger_on = ex.tel.enabled
+        # prediction.  Timing only when the ledger is on: without it the
+        # advance takes no sync and no clock
+        ledger_on = ex.tel.ledger.enabled
         pipes = [g for g in self.groups.values() if g.members] \
             + list(self.proj_members)
         # warmup gate: an advance whose pipelines include a first-step
@@ -534,22 +535,24 @@ class QueryServer:
 
     def submit(self, q, *, tenant: str = "default",
                deadline_s: Optional[float] = None) -> int:
-        node = q.node if isinstance(q, L.Q) else q
-        spec = self.tenants.get(tenant) or TenantSpec(tenant)
-        now = time.perf_counter()
-        deadline = now + deadline_s if deadline_s is not None \
-            else float("inf")
-        with self._lock:
-            qid = self._next_qid
-            self._next_qid += 1
-            self._pending.append(QueryRecord(
-                qid, node, t_submit=now, tenant=tenant,
-                priority=spec.priority, deadline=deadline))
-            self.n_submitted += 1
-            depth = len(self._pending)
-        self.executor.metrics.set("serve.queue_depth", depth)
-        self.executor.metrics.observe("serve.queue_depth_at_submit",
-                                      depth)
+        with self.executor.tel.span("serve.submit") as sp:
+            node = q.node if isinstance(q, L.Q) else q
+            spec = self.tenants.get(tenant) or TenantSpec(tenant)
+            now = time.perf_counter()
+            deadline = now + deadline_s if deadline_s is not None \
+                else float("inf")
+            with self._lock:
+                qid = self._next_qid
+                self._next_qid += 1
+                self._pending.append(QueryRecord(
+                    qid, node, t_submit=now, tenant=tenant,
+                    priority=spec.priority, deadline=deadline))
+                self.n_submitted += 1
+                depth = len(self._pending)
+            sp.set(qid=qid)
+            self.executor.metrics.set("serve.queue_depth", depth)
+            self.executor.metrics.observe("serve.queue_depth_at_submit",
+                                          depth)
         return qid
 
     def query(self, q):
@@ -634,7 +637,7 @@ class QueryServer:
         next overlay."""
         pol = self.policy
         ex = self.executor
-        if pol is None or not ex.tel.enabled:
+        if pol is None or not ex.tel.ledger.enabled:
             return
         agg, nxt = ex.tel.ledger.window_drift(
             self._ledger_pos, min_rows=pol.min_window_rows)
@@ -706,9 +709,10 @@ class QueryServer:
                 continue
             if self._serve_cached(rec, done):
                 continue
-            if self._try_attach(rec):
-                continue
-            res = self.executor.execute(rec.node)
+            with tm.request(rec.qid):
+                if self._try_attach(rec):
+                    continue
+                res = self.executor.execute(rec.node)
             rec.result = res.value
             self._complete_rec(rec)
             self.history.append(rec)
@@ -874,7 +878,10 @@ class QueryServer:
             batch, self._pending = self._pending, []
         if not batch:
             return {}
-        with self.executor.tel.span("serve.drain", batch=len(batch)):
+        # a lone query's id tags the whole drain; a larger batch's spans
+        # take each record's id where it executes
+        with tm.request(batch[0].qid if len(batch) == 1 else None), \
+                self.executor.tel.span("serve.drain", batch=len(batch)):
             return self._drain_batch(batch)
 
     def _drain_batch(self, batch: List[QueryRecord]) -> Dict[int, object]:
@@ -916,7 +923,8 @@ class QueryServer:
         # 3. the rest, one executor call each (plan cache still applies;
         # a semantic-cache hit skips execution entirely)
         for rec in singles:
-            res = self.executor.execute(rec.node)
+            with tm.request(rec.qid):
+                res = self.executor.execute(rec.node)
             rec.result = res.value
             if res.result_cache_hit:
                 rec.path = "cached"

@@ -6,11 +6,13 @@ actually delivered — exactly the modeled-vs-achieved gap the paper's
 follow-up work (Shuhai, "Benchmarking High Bandwidth Memory on FPGAs")
 exists to close.  This module is the measurement layer:
 
-* **Tracer** — low-overhead nested spans (plan -> optimize -> physical
-  costing -> exec/pipeline -> serve drain) plus instant events, exported
-  as a ``chrome://tracing``-loadable JSON.  Nesting is implicit in the
-  Chrome model: spans on one thread whose ``[ts, ts+dur]`` intervals
-  contain each other render nested.
+* **Tracer** — nested spans (serve -> exec -> pipeline / trainer) plus
+  instant events.  Every span is a ``jax.profiler.TraceAnnotation``, so
+  a profiler session records it in the same trace as the device's
+  operations, on one clock, tagged with its request's ``qid``.  An
+  enabled tracer also keeps each span as a ``chrome://tracing`` JSON
+  event.  Nesting is implicit in both: spans on one thread whose
+  intervals contain each other render nested.
 * **MetricsRegistry** — named counters and bounded-reservoir latency
   histograms with a flat ``snapshot()`` dict.  Each ``Executor`` owns a
   private registry (per-tenant counters stay separable); the tracer and
@@ -24,18 +26,23 @@ exists to close.  This module is the measurement layer:
   consumes — online recalibration is
   ``model._apply_calibration(ledger.calibration_overlay(model))``.
 
-Everything is env-gated: ``REPRO_TRACE=0`` (the default) makes every
-span a shared no-op singleton and every ledger record an early return —
-the disabled hot path is one attribute check, no allocation retained.
+Spans are always on and never fence; ``benchmarks/span_cost.py`` times
+one with no profiler recording.  ``REPRO_TRACE=1`` (default off) adds the
+Chrome sink and the ledger, and the ledger does change the timing path:
+it fences each measured pipeline with ``jax.block_until_ready``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import json
 import os
 import threading
 import time
-from typing import Callable, Dict, Iterable, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # --------------------------------------------------------------------------- #
 # gating
@@ -50,52 +57,62 @@ def trace_enabled() -> bool:
 # --------------------------------------------------------------------------- #
 # spans
 
-class _NullSpan:
-    """Shared no-op span: the entire disabled path.  One module-level
-    singleton, so a disabled ``tracer.span(...)`` allocates nothing that
-    outlives the call."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **args) -> "_NullSpan":
-        return self
+# the request a span belongs to: ``QueryServer`` sets it around each
+# record's execution, and every span opened beneath carries it as ``qid``
+_QID: contextvars.ContextVar = contextvars.ContextVar("repro_qid",
+                                                      default=None)
 
 
-NULL_SPAN = _NullSpan()
+@contextlib.contextmanager
+def request(qid):
+    """Tag every span opened inside with the request id ``qid`` (None
+    leaves the spans untagged)."""
+    token = _QID.set(qid)
+    try:
+        yield
+    finally:
+        _QID.reset(token)
 
 
 class _Span:
-    """One live span: records wall-clock bounds on exit and appends the
-    finished event to its tracer."""
+    """One live span.  It always opens a ``jax.profiler.TraceAnnotation``
+    of its name, which records nothing when no profiler session is
+    recording and otherwise lands in the profiler's trace on the
+    device's clock; with a recording ``tracer`` it also appends its
+    Chrome event on exit.  A span never fences the device."""
 
-    __slots__ = ("tracer", "name", "t0", "args", "tid")
+    __slots__ = ("tracer", "name", "t0", "args", "tid", "ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    def __init__(self, tracer: Optional["Tracer"], name: str, args: dict):
+        qid = _QID.get()
+        if qid is not None:
+            args["qid"] = qid
+        self.ann = TraceAnnotation(name, **args)
         self.tracer = tracer
         self.name = name
-        self.args = args
-        self.tid = threading.get_ident()
-        self.t0 = time.perf_counter()
+        self.args = args if tracer is not None else None
 
     def __enter__(self):
+        self.ann.__enter__()
+        if self.tracer is not None:
+            self.tid = threading.get_ident()
+            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.tracer._finish(self.name, self.t0,
-                            time.perf_counter() - self.t0, self.tid,
-                            self.args)
+        if self.tracer is not None:
+            self.tracer._finish(self.name, self.t0,
+                                time.perf_counter() - self.t0, self.tid,
+                                self.args)
+        self.ann.__exit__(*exc)
         return False
 
     def set(self, **args) -> "_Span":
         """Attach attributes discovered mid-span (path taken, cache
         outcome, reason strings)."""
-        self.args.update(args)
+        self.ann.set_metadata(**args)
+        if self.args is not None:
+            self.args.update(args)
         return self
 
 
@@ -114,10 +131,10 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------- #
 
-    def span(self, name: str, **args):
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name, args)
+    def span(self, name: str, **args) -> _Span:
+        """A span of ``name``: always a profiler annotation, and a Chrome
+        event too while this tracer is enabled."""
+        return _Span(self if self.enabled else None, name, args)
 
     def _finish(self, name: str, t0: float, dur: float, tid: int,
                 args: dict) -> None:
@@ -127,8 +144,8 @@ class Tracer:
             "args": args})
 
     def complete(self, name: str, t0: float, dur: float, **args) -> None:
-        """Record an already-measured interval (the per-morsel loop times
-        with its own clock and reports here)."""
+        """Record an already-measured interval in the Chrome sink only
+        (the eager lowering's fenced per-operator ``op.*`` rows)."""
         if not self.enabled:
             return
         self._finish(name, t0, dur, threading.get_ident(), args)
@@ -628,10 +645,8 @@ class Telemetry:
         self.metrics = MetricsRegistry()
 
     # thin delegates, so instrumentation sites hold one object
-    def span(self, name: str, **args):
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self.tracer, name, args)
+    def span(self, name: str, **args) -> _Span:
+        return self.tracer.span(name, **args)
 
     def instant(self, name: str, **args) -> None:
         if self.enabled:

@@ -20,6 +20,7 @@ from repro.query import (
     Catalog, Executor, PlacementCapacityError, Q, QueryServer, analyze,
 )
 from repro.query.optimize import choose_build_side, optimize
+from repro.query import telemetry as tm
 
 # n = 4096; 1000 does not divide it, 4096 is one morsel, 9999 over-covers
 MORSEL_SWEEP = (256, 1000, 4096, 9999)
@@ -266,7 +267,7 @@ def test_train_glm_stream_matches_whole_column(rng):
         epochs=3)
     xs_stream, losses_stream = engine.train_glm_stream(
         big, ["f0", "f1", "f2"], "y", grid, ex.plans["partitioned"],
-        epochs=3, morsel_rows=128)
+        epochs=3, morsel_rows=128, telemetry=tm.Telemetry(False))
     np.testing.assert_allclose(np.asarray(xs_stream),
                                np.asarray(xs_full), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(losses_stream),

@@ -3,12 +3,16 @@ trace schema/nesting, bandwidth-ledger drift exactness on a plan whose
 cardinality estimates are provably exact, consolidated executor metrics
 (back-compat properties included), and honest serving sojourns."""
 import json
+import sys
 import time
 
 import numpy as np
 import pytest
 
+import jax
+
 from repro.columnar.table import Table
+from repro.core.sgd_glm import HyperParams
 from repro.query import (
     Catalog, CostModel, Executor, Q, QueryServer,
 )
@@ -47,12 +51,15 @@ def test_disabled_records_nothing():
 
 
 def test_disabled_span_is_shared_singleton():
-    """The disabled path allocates no per-query span objects: every
-    ``span()`` call returns ONE module-level null singleton."""
+    """A disabled span is only the profiler's annotation: it records no
+    event, keeps none of its attributes, and nothing but its caller holds
+    it, so no span outlives its query."""
     tel = tm.Telemetry(enabled=False)
-    spans = {id(tel.span("a")), id(tel.span("b", k=1)),
-             id(tm.NULL_SPAN)}
-    assert len(spans) == 1
+    with tm.request(7), tel.span("a", k=1) as sp:
+        assert sp.set(path="x") is sp
+    assert sp.args is None
+    assert tel.tracer.events == [] and tel.tracer.dropped == 0
+    assert sys.getrefcount(sp) == 2            # the local and the argument
 
 
 def test_disabled_no_container_growth():
@@ -107,6 +114,115 @@ def test_chrome_trace_schema_and_nesting(tmp_path):
         assert _contains(plan, by_name[name][0])
     assert _contains(execute, plan)
     assert execute["args"]["path"] == "batch"
+
+
+def _train_catalog(m=500, seed=0):
+    rng = np.random.default_rng(seed)
+    cols = {f"f{i}": rng.normal(size=m).astype(np.float32)
+            for i in range(3)}
+    cols["y"] = (cols["f0"] - cols["f1"] > 0).astype(np.float32)
+    return Catalog.from_tables(Table.from_arrays("g", cols))
+
+
+def _train_q(epochs=1):
+    return Q.scan("g").train_glm(["f0", "f1", "f2"], "y",
+                                 [HyperParams(0.1, 0.0),
+                                  HyperParams(0.05, 0.01)], epochs=epochs)
+
+
+def _host_events(trace_dir, layers):
+    """``(name, start, end, stats)`` of the profiler trace's host events
+    whose names start with one of ``layers`` and a dot."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name.split(".")[0] in layers]
+
+
+def test_program_spans_nest_in_the_profiler_trace(tmp_path):
+    """With the Chrome sink off, one served train query's spans land in
+    the profiler's trace on the host clock, nested inside a benchmark
+    annotation, each tagged with the query's id."""
+    ex = Executor(_train_catalog(), telemetry=tm.Telemetry(enabled=False))
+    srv = QueryServer(ex)
+    srv.query(_train_q())                       # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        qid = srv.submit(_train_q())
+        with jax.profiler.TraceAnnotation("bench.drain"):
+            out = srv.drain()
+    finally:
+        jax.profiler.stop_trace()
+    assert qid in out
+    evs = _host_events(tmp_path, ("bench", "serve", "exec", "trainer"))
+    one = {n: (s, e, st) for n, s, e, st in evs}
+    chain = ["bench.drain", "serve.drain", "exec.execute", "exec.run_train",
+             "trainer.epoch_step"]
+    for outer, inner in zip(chain, chain[1:]):
+        assert one[outer][0] <= one[inner][0] <= one[inner][1] \
+            <= one[outer][1], (outer, inner)
+    program = [st for n, s, e, st in evs
+               if n.split(".")[0] in ("serve", "exec", "trainer")]
+    assert {"serve.submit", "trainer.stage", "trainer.loss_step"} <= set(one)
+    assert {st.get("qid") for st in program} == {qid}
+
+
+@pytest.mark.parametrize("path", ["fused", "stream", "train", "serve"])
+def test_fences_only_with_the_ledger(path, monkeypatch):
+    """With the ledger off, no span path waits on the device; with it on,
+    each measured path fences as before."""
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+
+    def run(tel):
+        if path == "train":
+            ex = Executor(_train_catalog(), telemetry=tel)
+            ex.execute(_train_q(), morsel_rows=208)
+            return
+        cat, _ = _exact_catalog()
+        ex = Executor(cat, telemetry=tel)
+        if path == "fused":
+            ex.execute(_scan_filter_sum())
+        elif path == "stream":
+            ex.execute(_scan_filter_sum(), mode="stream",
+                       morsel_rows=1 << 12)
+        else:
+            srv = QueryServer(ex, streaming=True, morsel_rows=1 << 12)
+            for lo in (3, 3, 4):     # the first advance warms the stream
+                srv.submit(_scan_filter_sum(lo, 60))
+                srv.drain()
+
+    run(tm.Telemetry(enabled=False))           # compile unpatched
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    run(tm.Telemetry(enabled=False))
+    assert calls == []
+    tel = tm.Telemetry(enabled=True)
+    run(tel)
+    assert calls and tel.ledger.rows
+
+
+def test_trainer_trace_count_repeats():
+    """Each call traces the trainer's two steps anew (fresh closures),
+    and the count repeats on a second run of the same query."""
+    ex = Executor(_train_catalog(), telemetry=tm.Telemetry(enabled=False))
+    counts = []
+    for _ in range(2):
+        before = ex.metrics.value("trainer.traces")
+        ex.execute(_train_q(epochs=2), morsel_rows=208)
+        counts.append(ex.metrics.value("trainer.traces") - before)
+    # 500 rows in morsels of 208, 208 and 96 (the tail, padded to the
+    # minibatch).  Each step traces 3 times whatever the epochs: its first
+    # call takes the fresh zeros carry, not yet committed to a device,
+    # so the second 208-row morsel traces again; then the 96-row tail
+    assert counts == [6, 6]
 
 
 def test_trace_bounded_by_max_events():
