@@ -23,6 +23,7 @@ from repro.core import sgd_glm
 from repro.core.channels import ChannelPlan
 from repro.kernels.join import ref as join_ref
 from repro.kernels.sgd import ref as sgd_ref
+from repro.kernels.sgd.sgd import sgd_block
 
 
 # rows per engine block of the eager selection (``Executor._filter_table``
@@ -307,6 +308,15 @@ def aggregate_sum_stream(carry, values: jax.Array, mask: jax.Array,
     return carry + jnp.sum(values * w)
 
 
+def sgd_kernel_applies(mesh) -> bool:
+    """Whether ``train_glm_stream`` runs its SGD loop as the Pallas kernel
+    (``kernels/sgd/sgd.sgd_block``): on a mesh of one TPU device.  The CPU
+    runs the XLA loop, and so does a mesh of several devices, where the
+    dataset is replicated and GSPMD would have to partition a custom
+    call.  The two paths stage the morsel in different layouts."""
+    return mesh.devices.size == 1 and mesh.devices.flat[0].platform == "tpu"
+
+
 def train_glm_stream(table: Table, features: Sequence[str], label: str,
                      grid, plan: ChannelPlan, *, kind: str = "logreg",
                      epochs: int = 5, minibatch: int = 16,
@@ -332,13 +342,24 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     ``on_morsel(n_bytes, seconds, tier)`` observes each promotion, and
     fences each morsel's staging to time it.
 
+    On one TPU chip (``sgd_kernel_applies``) each morsel is staged
+    feature-major, ``(features + 1, rows)`` with the label as the last
+    row, and the SGD loop is the Pallas kernel ``sgd_block``; elsewhere
+    the morsel is ``(rows, features)`` plus the label and the loop is
+    ``sgd_ref`` under XLA.  Both apply one update per minibatch of the
+    morsel's rows padded to the minibatch; the kernel sums its products
+    in float32 in another order, so its weights match ``train_glm`` to
+    float32 rounding, not bit for bit.
+
     Spans of ``telemetry`` (any object whose ``span(name, **attrs)`` is
     a context manager): ``trainer.stage`` per morsel staged (slice, casts,
     stack, ``device_put``), ``trainer.epoch_step`` per morsel and epoch and
     ``trainer.loss_step`` per morsel, each around the step's dispatch,
-    including any trace or compile of its program.  Each trace of either
-    step opens a ``trainer.trace`` span and counts one ``trainer.traces``
-    in ``metrics``."""
+    including any trace or compile of its program; ``trainer.epoch_step``
+    carries ``impl="pallas"`` or ``"xla"``.  Each trace of either step
+    opens a ``trainer.trace`` span and counts one ``trainer.traces`` in
+    ``metrics``; each kernel dispatch counts one
+    ``trainer.sgd_kernel_calls``."""
     m = table.num_rows
     if morsel_rows is None:
         morsel_rows = m
@@ -351,6 +372,8 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     l2s = jnp.array([g.l2 for g in grid], jnp.float32)
     xs = jnp.zeros((k, len(features)), jnp.float32)
     rep = NamedSharding(plan.mesh, P())      # dataset replication (Fig. 10a)
+    kernel = sgd_kernel_applies(plan.mesh)
+    impl = "pallas" if kernel else "xla"
 
     def traced():
         # runs in the steps' Python bodies, so once per trace; the span
@@ -366,22 +389,31 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
             # Table.morsel pads the ragged tail to spec.rows; keep only up
             # to the next minibatch multiple past the valid rows
             rows_pad = -(-n_valid // minibatch) * minibatch
-            a = jnp.stack([jnp.asarray(data[f][:rows_pad])
-                           .astype(jnp.float32) for f in features], axis=1)
-            b = jnp.asarray(data[label][:rows_pad]).astype(jnp.float32)
-            a, b = jax.device_put(a, rep), jax.device_put(b, rep)
+            vals = [jnp.asarray(data[c][:rows_pad]).astype(jnp.float32)
+                    for c in cols]
+            if kernel:
+                # the kernel's layout: rows on the lanes, the label as
+                # the last feature row, one block DMA for both
+                arrays = (jnp.stack(vals, axis=0),)
+            else:
+                arrays = (jnp.stack(vals[:-1], axis=1), vals[-1])
+            arrays = tuple(jax.device_put(x, rep) for x in arrays)
             if on_morsel is not None:
-                jax.block_until_ready(b)
+                jax.block_until_ready(arrays)
                 tiers = {table.column_tier(c) for c in cols}
                 worst = "disk" if "disk" in tiers else \
                     ("host" if "host" in tiers else "device")
-                on_morsel(a.nbytes + b.nbytes, time.perf_counter() - t0,
-                          worst)
-            return a, b, n_valid
+                on_morsel(sum(x.nbytes for x in arrays),
+                          time.perf_counter() - t0, worst)
+            return arrays, n_valid
 
     @jax.jit
-    def epoch_step(xs, a_m, b_m):
+    def epoch_step(xs, lrs, l2s, *arrays):
         traced()
+        if kernel:
+            return sgd_block(arrays[0], lrs, l2s, xs, minibatch=minibatch,
+                             kind=kind)
+        a_m, b_m = arrays
 
         def one(x, lr, l2):
             return sgd_ref.sgd_ref(a_m, b_m, x, lr=lr, l2=l2,
@@ -389,31 +421,39 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
         return jax.vmap(one)(xs, lrs, l2s)
 
     @jax.jit
-    def loss_step(acc, a_m, b_m, n_valid, xs):
+    def loss_step(acc, xs, n_valid, *arrays):
         traced()
-        valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
 
-        def rowsum(x):
-            z = a_m @ x
+        def row_loss(z, b):
             if kind == "logreg":
                 p = jax.nn.sigmoid(z)
                 eps = 1e-7
-                j = -(b_m * jnp.log(p + eps)
-                      + (1 - b_m) * jnp.log(1 - p + eps))
-            else:
-                j = 0.5 * jnp.square(z - b_m)
-            return jnp.sum(j * valid)
-        return acc + jax.vmap(rowsum)(xs)
+                return -(b * jnp.log(p + eps) + (1 - b) * jnp.log(1 - p + eps))
+            return 0.5 * jnp.square(z - b)
+
+        if kernel:
+            (d_m,) = arrays
+            valid = (jnp.arange(d_m.shape[1]) < n_valid).astype(jnp.float32)
+            # the label row meets a zero weight
+            z = jnp.pad(xs, ((0, 0), (0, 1))) @ d_m
+            return acc + jnp.sum(row_loss(z, d_m[-1]) * valid, axis=1)
+        a_m, b_m = arrays
+        valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
+        return acc + jax.vmap(
+            lambda x: jnp.sum(row_loss(a_m @ x, b_m) * valid))(xs)
 
     for e in range(epochs):
         for i in range(spec.n_morsels):
-            a_m, b_m, _ = morsel_arrays(i)
-            with telemetry.span("trainer.epoch_step", morsel=i, epoch=e):
-                xs = epoch_step(xs, a_m, b_m)
+            arrays, _ = morsel_arrays(i)
+            with telemetry.span("trainer.epoch_step", morsel=i, epoch=e,
+                                impl=impl):
+                if kernel and metrics is not None:
+                    metrics.inc("trainer.sgd_kernel_calls")
+                xs = epoch_step(xs, lrs, l2s, *arrays)
     acc = jnp.zeros((k,), jnp.float32)
     for i in range(spec.n_morsels):
-        a_m, b_m, n_valid = morsel_arrays(i)
+        arrays, n_valid = morsel_arrays(i)
         with telemetry.span("trainer.loss_step", morsel=i):
-            acc = loss_step(acc, a_m, b_m, jnp.int32(n_valid), xs)
+            acc = loss_step(acc, xs, jnp.int32(n_valid), *arrays)
     losses = acc / m + l2s * jnp.sum(jnp.square(xs), axis=1)
     return xs, losses

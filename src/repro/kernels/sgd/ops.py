@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.sgd import ref
-from repro.kernels.sgd.sgd import sgd_pallas
+from repro.kernels.sgd.sgd import sgd_block
 
 
 @partial(jax.jit, static_argnames=("lr", "l2", "minibatch", "epochs", "kind",
@@ -16,7 +16,11 @@ def sgd_train(a, b, x0, *, lr: float, l2: float = 0.0, minibatch: int = 16,
               epochs: int = 1, kind: str = "ridge", impl: str = "xla",
               interpret: bool = False):
     if impl == "pallas":
-        return sgd_pallas(a, b, x0, lr=lr, l2=l2, minibatch=minibatch,
-                          epochs=epochs, kind=kind, interpret=interpret)
+        # the kernel's single-model case, on the feature-major layout
+        data = jnp.concatenate([a.T, b[None]], axis=0)
+        x = sgd_block(data, jnp.full((1,), lr), jnp.full((1,), l2),
+                      x0[None], minibatch=minibatch, epochs=epochs,
+                      kind=kind, interpret=interpret)
+        return x[0]
     return ref.sgd_ref(a, b, x0, lr=lr, l2=l2, minibatch=minibatch,
                        epochs=epochs, kind=kind)

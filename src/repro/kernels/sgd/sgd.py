@@ -1,17 +1,34 @@
-"""Pallas TPU kernel for pipelined minibatch SGD (paper §VI, Fig. 9).
+"""Pallas TPU kernel for pipelined minibatch SGD of K GLMs (paper §VI,
+Fig. 9).
 
-TPU adaptation of the paper's dataflow engine: the model x lives in VMEM
-scratch for the WHOLE run (the paper keeps it in on-chip registers/BRAM);
-the dataset streams HBM->VMEM one minibatch block per sequential grid step
-(Pallas double-buffers the incoming block while the previous one computes —
-the ingress FIFO of Fig. 9).  Dot / ScalarEngine / Update are the three
-fused stages inside the kernel body.  Grid iteration order IS the RAW
-dependency the paper preserves: ``dimension_semantics=("arbitrary",)``
-forbids reordering, so convergence matches the oracle bit-for-bit modulo
-float addition order.
+TPU adaptation of the paper's dataflow engine: the K models' weights stay
+on chip for the WHOLE call (the paper keeps its model in on-chip
+registers/BRAM), in the output block, which every grid step maps to the
+same place.  The dataset streams HBM->VMEM feature-major, ``block_rows``
+rows per sequential grid step: features on sublanes, rows on lanes, the
+label as the last feature row.  Pallas double-buffers the next block while
+this one computes (the ingress FIFO of Fig. 9).
 
-Epochs are folded into the grid (step e*nb + i reads block i), mirroring
-the paper's iterative rescans of the HBM-resident dataset.
+Inside a block a ``fori_loop`` walks 128-row tiles, and each tile's
+``128 // minibatch`` minibatches update the K models one after another,
+in table order.  A model is held broadcast over the 128 lanes, one
+feature per sublane, so the three stages are plain VPU work in float32,
+for the K models at once: Dot (a sublane sum of tile x weights, one value
+per row), ScalarEngine (the link, minus the label, kept on the
+minibatch's lanes only) and Update (a lane sum per feature: the
+minibatch's gradient, already on every lane for the next step).  One step
+costs a few dozen vector instructions, with no loop trip of XLA's and no
+launch between steps.  ``dimension_semantics=("arbitrary",)`` keeps the
+grid in order: the RAW dependency the paper preserves.
+
+Exactly ``m // minibatch`` updates run per epoch: the number of
+minibatches arrives as a scalar-prefetch operand and bounds the last
+block's loop, so the lanes that pad the last block apply no update (a
+pure-pad minibatch would still apply the l2 shrinkage).  ``lr`` and ``l2``
+are operands, one value per model, so one compiled kernel serves every
+grid of K models at a shape.  Epochs are folded into the grid (step
+e*nb + i reads block i), mirroring the paper's iterative rescans of the
+HBM-resident dataset.
 """
 from __future__ import annotations
 
@@ -19,62 +36,124 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+BLOCK_ROWS = 8192       # rows per grid step: 64 tiles of 128 lanes
 
-def _sgd_kernel(a_ref, b_ref, x0_ref, xout_ref, x_vmem, *,
-                lr: float, l2: float, kind: str, nb: int, epochs: int):
+
+def _link(kind: str, z):
+    return jax.nn.sigmoid(z) if kind == "logreg" else z
+
+
+def _zero_unless(keep, x):
+    """``x`` where ``keep``, else 0.  The kernel keeps to ``lax`` for this
+    and for its integer division: ``jnp.where``, ``//`` and ``%`` trace to
+    nested jits, which the trainer lowers again on every call."""
+    keep = lax.broadcast_in_dim(keep, x.shape,
+                                tuple(range(x.ndim - keep.ndim, x.ndim)))
+    return lax.select(keep, x, jnp.zeros_like(x))
+
+
+def _sgd_kernel(nmb_ref, lr_ref, l2_ref, data_ref, x0_ref, x_ref, *,
+                kind: str, minibatch: int, n_blocks: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
-        x_vmem[...] = x0_ref[...]
+        x_ref[...] = x0_ref[...]
 
-    a = a_ref[...]                                   # (B, n) minibatch block
-    b = b_ref[...]                                   # (B, 1)
-    x = x_vmem[...]                                  # (1, n)
-    z = jax.lax.dot_general(a, x, (((1,), (1,)), ((), ())),      # Dot
-                            preferred_element_type=jnp.float32)  # (B, 1)
-    if kind == "logreg":
-        z = jax.nn.sigmoid(z)                        # ScalarEngine
-    d = z - b
-    g = jax.lax.dot_general(d, a, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32) / a.shape[0]
-    x = x - lr * (g + 2.0 * l2 * x)                  # Update (RAW preserved)
-    x_vmem[...] = x
+    k, n1, _ = x_ref.shape
+    block_rows = data_ref.shape[1]
+    per_tile = LANES // minibatch
+    per_block = block_rows // minibatch
+    todo = jnp.minimum(nmb_ref[0] - lax.rem(step, n_blocks) * per_block,
+                       per_block)
+    lr = lr_ref[...]                                   # (k, 1, 1)
+    l2x2 = 2.0 * l2_ref[...]
+    feature = lax.broadcasted_iota(jnp.int32, (n1, LANES), 0) < n1 - 1
+    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    lane_mb = lax.div(lane, minibatch)            # each lane's minibatch
+    inv_mb = 1.0 / minibatch      # exact: a minibatch divides 128 lanes
 
-    @pl.when(step == nb * epochs - 1)
-    def _emit():
-        xout_ref[...] = x
+    def load(t):
+        """Tile t of the block, each row on its lane: its features (the
+        label row zeroed) and its labels.  Lanes past the block's last
+        minibatch are not data, so they are zeroed too."""
+        tile = data_ref[:, pl.ds(pl.multiple_of(t * LANES, LANES), LANES)]
+        valid = lane < (todo - t * per_tile) * minibatch
+        return (_zero_unless(feature & valid, tile),
+                _zero_unless(valid, tile[n1 - 1:, :]))
+
+    def update(i, a, b, x):
+        """Minibatch i of the tile (a, b) applied to the k models x."""
+        z = jnp.sum(a * x, axis=1, keepdims=True)              # Dot
+        d = _zero_unless(lane_mb == i, _link(kind, z) - b)     # ScalarEngine
+        g = jnp.sum(a * d, axis=2, keepdims=True) * inv_mb
+        return x - lr * (g + l2x2 * x)                         # Update
+
+    def tile(t, x):
+        # one update in the loop body, not one per minibatch of the tile:
+        # the trainer lowers this kernel again on every call
+        a, b = load(t)
+        return lax.fori_loop(
+            0, jnp.minimum(per_tile, todo - t * per_tile),
+            lambda i, x: update(i, a, b, x), x)
+
+    x_ref[...] = lax.fori_loop(0, lax.div(todo + per_tile - 1, per_tile),
+                               tile, x_ref[...])
 
 
-def sgd_pallas(a, b, x0, *, lr: float, l2: float = 0.0, minibatch: int = 16,
-               epochs: int = 1, kind: str = "ridge",
-               interpret: bool = False):
-    """a: (m, n) f32; b: (m,); x0: (n,). Returns trained x (n,).
+@functools.partial(jax.jit, static_argnames=(
+    "minibatch", "epochs", "kind", "block_rows", "interpret"))
+def sgd_block(data, lr, l2, x0, *, minibatch: int = 16, epochs: int = 1,
+              kind: str = "ridge", block_rows: int = BLOCK_ROWS,
+              interpret: bool = False):
+    """Train K models side by side over one feature-major dataset.
 
-    Labels and the model travel as 2-D (m, 1) / (1, n) blocks: the TPU
-    lowering tiles rank-1 blocks by 128 lanes, which a 16-row minibatch
-    of labels is not."""
-    m, n = a.shape
-    assert m % minibatch == 0
-    nb = m // minibatch
-    kernel = functools.partial(_sgd_kernel, lr=lr, l2=l2, kind=kind,
-                               nb=nb, epochs=epochs)
+    data: (n + 1, m) float32, rows 0..n-1 the features and row n the
+    label, m a multiple of ``minibatch``; lr, l2: (K,) float32; x0: (K, n)
+    float32.  Returns the trained (K, n) weights: ``epochs`` passes of
+    ``m // minibatch`` updates each, ``x <- x - lr * (g + 2 l2 x)`` with g
+    the minibatch's mean gradient."""
+    n1, m = data.shape
+    k, n = x0.shape
+    if n1 != n + 1:
+        raise ValueError(f"data has {n1} rows, expected {n} features + "
+                         "the label")
+    if m % minibatch or LANES % minibatch:
+        raise ValueError(f"{m} rows in minibatches of {minibatch}: need a "
+                         "whole number of minibatches, each dividing "
+                         f"{LANES} rows")
+    block_rows = min(block_rows, pl.cdiv(m, LANES) * LANES)
+    n_blocks = pl.cdiv(m, block_rows)
+    kernel = functools.partial(_sgd_kernel, kind=kind, minibatch=minibatch,
+                               n_blocks=n_blocks)
+    # a model's lr and l2 broadcast along its weights
+    hyper = pl.BlockSpec((k, 1, 1), lambda i, nmb: (0, 0, 0))
+    # each model one feature per sublane, broadcast over the lanes; the
+    # label's row stays zero
+    models = pl.BlockSpec((k, n1, LANES), lambda i, nmb: (0, 0, 0))
+    x0 = jnp.broadcast_to(jnp.pad(x0, ((0, 0), (0, 1)))[:, :, None],
+                          (k, n1, LANES))
     x = pl.pallas_call(
         kernel,
-        grid=(nb * epochs,),
-        in_specs=[
-            pl.BlockSpec((minibatch, n), lambda i: (i % nb, 0)),
-            pl.BlockSpec((minibatch, 1), lambda i: (i % nb, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(epochs * n_blocks,),
+            in_specs=[hyper, hyper,
+                      pl.BlockSpec((n1, block_rows),
+                                   lambda i, nmb: (0, lax.rem(i, n_blocks))),
+                      models],
+            out_specs=models),
+        out_shape=jax.ShapeDtypeStruct((k, n1, LANES), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),     # sequential: RAW dep
         interpret=interpret,
-    )(a, b.reshape(m, 1), x0.reshape(1, n))
-    return x.reshape(n)
+        name="sgd_block",
+    )(jnp.full((1,), m // minibatch, jnp.int32),
+      lr.astype(jnp.float32).reshape(k, 1, 1),
+      l2.astype(jnp.float32).reshape(k, 1, 1), data, x0.astype(jnp.float32))
+    return x[:, :n, 0]

@@ -1,4 +1,6 @@
-"""SGD kernel: bit-exact vs oracle across shapes/kinds + convergence props."""
+"""SGD kernel: K models against the vmapped oracle across shapes/kinds,
+exact update counts, and convergence props."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -6,19 +8,81 @@ from _hyp import given, settings, st
 
 from repro.kernels.sgd.ops import sgd_train
 from repro.kernels.sgd.ref import loss_ref, sgd_ref
+from repro.kernels.sgd.sgd import sgd_block
+
+LRS = [0.05, 0.2, 0.02, 0.001]
+L2S = [1e-4, 0.0, 0.01, 0.1]
 
 
-@pytest.mark.parametrize("m,n,mb", [(128, 64, 8), (256, 128, 16),
-                                    (512, 256, 32)])
+def _kernel(data, lr, l2, x0, **kw):
+    """The kernel with lr/l2 as traced operands, in interpret mode."""
+    return jax.jit(lambda d, lr, l2, x: sgd_block(
+        d, lr, l2, x, interpret=True, **kw))(data, lr, l2, x0)
+
+
+def _feature_major(a, b):
+    return jnp.concatenate([a.T, b[None]], axis=0)
+
+
+@pytest.mark.parametrize("m,n,mb,k,epochs", [
+    # the single-model kernel's shapes, which this kernel replaced
+    (128, 64, 8, 1, 3), (256, 128, 16, 1, 3), (512, 256, 32, 1, 3),
+    # the trainer's minibatch at HIGGS's width, in blocks of 256 rows:
+    # whole blocks, a ragged last block, one block padded only to 16
+    (512, 28, 16, 1, 1), (560, 28, 16, 1, 1), (208, 28, 16, 1, 1),
+    (512, 28, 16, 4, 1), (560, 28, 16, 4, 1), (208, 28, 16, 4, 1),
+])
 @pytest.mark.parametrize("kind", ["ridge", "logreg"])
-def test_pallas_bitexact_vs_ref(rng, m, n, mb, kind):
+def test_sgd_block_matches_vmapped_ref(rng, m, n, mb, k, epochs, kind):
+    """K models side by side against ``jax.vmap(sgd_ref)``: the same
+    updates in the same order, summed in another order (float32 VPU sums
+    in the kernel), hence a relative tolerance and not bit equality."""
     a = jnp.asarray(rng.uniform(-1, 1, size=(m, n)), jnp.float32)
     b = jnp.asarray(rng.uniform(0, 1, size=m), jnp.float32)
-    x0 = jnp.zeros(n, jnp.float32)
-    xr = sgd_ref(a, b, x0, lr=0.05, l2=1e-4, minibatch=mb, epochs=3, kind=kind)
-    xp = sgd_train(a, b, x0, lr=0.05, l2=1e-4, minibatch=mb, epochs=3,
-                   kind=kind, impl="pallas", interpret=True)
-    np.testing.assert_allclose(np.asarray(xr), np.asarray(xp), rtol=0, atol=0)
+    x0 = jnp.asarray(rng.normal(size=(k, n)) * 0.1, jnp.float32)
+    lr = jnp.asarray(LRS[:k], jnp.float32)
+    l2 = jnp.asarray(L2S[:k], jnp.float32)
+    want = jax.vmap(lambda x, lr, l2: sgd_ref(
+        a, b, x, lr=lr, l2=l2, minibatch=mb, epochs=epochs, kind=kind))(
+        x0, lr, l2)
+    got = _kernel(_feature_major(a, b), lr, l2, x0, minibatch=mb,
+                  epochs=epochs, kind=kind, block_rows=256)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5 * float(
+                                   jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("m", [208, 560])
+def test_sgd_block_applies_no_pad_update(m):
+    """On all-zero rows a ridge update only shrinks: x <- x (1 - 2 lr l2).
+    After m // 16 minibatches the weights carry exactly that many
+    factors, not one more for each 16 pad rows of the last 256-row
+    block."""
+    n, k = 28, 4
+    x0 = jnp.ones((k, n), jnp.float32)
+    lr = jnp.asarray(LRS, jnp.float32)
+    l2 = jnp.asarray(L2S, jnp.float32)
+    got = _kernel(jnp.zeros((n + 1, m), jnp.float32), lr, l2, x0,
+                  minibatch=16, kind="ridge", block_rows=256)
+    shrink = np.asarray(1 - 2 * lr * l2, np.float64)[:, None]
+    np.testing.assert_allclose(np.asarray(got), np.broadcast_to(
+        shrink ** (m // 16), (k, n)), rtol=1e-5)
+    assert not np.allclose(np.asarray(got)[3], shrink[3] ** (m // 16 + 1),
+                           rtol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ["ridge", "logreg"])
+def test_sgd_train_pallas_is_the_single_model_kernel(rng, kind):
+    """``sgd_train(impl="pallas")`` is the kernel's K=1 case on the
+    feature-major layout."""
+    a = jnp.asarray(rng.uniform(-1, 1, size=(256, 28)), jnp.float32)
+    b = jnp.asarray(rng.uniform(0, 1, size=256), jnp.float32)
+    x0 = jnp.zeros(28, jnp.float32)
+    got = sgd_train(a, b, x0, lr=0.05, l2=1e-4, epochs=2, kind=kind,
+                    impl="pallas", interpret=True)
+    want = _kernel(_feature_major(a, b), jnp.asarray([0.05]),
+                   jnp.asarray([1e-4]), x0[None], epochs=2, kind=kind)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want)[0])
 
 
 @settings(max_examples=10, deadline=None)

@@ -225,6 +225,43 @@ def test_trainer_trace_count_repeats():
     assert counts == [6, 6]
 
 
+def _epoch_step_impls(tel):
+    return [e["args"]["impl"] for e in tel.tracer.events
+            if e["name"] == "trainer.epoch_step"]
+
+
+def test_trainer_runs_the_xla_loop_on_cpu():
+    """On the CPU the trainer takes the XLA loop: no kernel dispatch is
+    counted and every epoch step's span says so."""
+    tel = tm.Telemetry(enabled=True)
+    ex = Executor(_train_catalog(), telemetry=tel)
+    ex.execute(_train_q(epochs=2), morsel_rows=208)
+    assert ex.metrics.value("trainer.sgd_kernel_calls") == 0
+    assert _epoch_step_impls(tel) == ["xla"] * 6
+
+
+def test_trainer_counts_each_kernel_dispatch(monkeypatch):
+    """With the kernel's rule forced (and the kernel interpreted, as the
+    CPU needs), each epoch step of each morsel dispatches the kernel once,
+    counted and named on its span, and the feature-major path trains and
+    scores as the XLA loop does."""
+    from functools import partial
+    from repro.columnar import engine
+    want = Executor(_train_catalog()).execute(_train_q(epochs=2),
+                                              morsel_rows=208)
+    monkeypatch.setattr(engine, "sgd_kernel_applies", lambda mesh: True)
+    monkeypatch.setattr(engine, "sgd_block",
+                        partial(engine.sgd_block, interpret=True))
+    tel = tm.Telemetry(enabled=True)
+    ex = Executor(_train_catalog(), telemetry=tel)
+    got = ex.execute(_train_q(epochs=2), morsel_rows=208)
+    # 500 rows in morsels of 208, 208 and 96, two epochs
+    assert ex.metrics.value("trainer.sgd_kernel_calls") == 6
+    assert _epoch_step_impls(tel) == ["pallas"] * 6
+    for g, w in zip(got.value, want.value):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5)
+
+
 def test_trace_bounded_by_max_events():
     tel = tm.Telemetry(enabled=True)
     tel.tracer.max_events = 10

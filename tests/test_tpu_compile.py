@@ -12,13 +12,14 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.columnar import engine
 from repro.columnar.table import Column, Table
 from repro.kernels.selection import ops as sel_ops
-from repro.kernels.sgd.sgd import sgd_pallas
+from repro.kernels.sgd.sgd import sgd_block
 from repro.query import logical as L
 from repro.query import pipeline as pl
 from repro.query.cost import ColumnStats, CostModel, TableStats, PALLAS_OPS
@@ -31,24 +32,30 @@ HBM_BYTES = 16 << 30            # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One described v5e chip, with JAX's persistent compilation cache
+def topo():
+    """A described v5e 2x2 host, with JAX's persistent compilation cache
     off: a program compiled for a chip that is not attached cannot be
     read back from it."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:          # noqa: BLE001 - any failure means "cannot"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described v5e chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, *shape, dtype=jnp.int32):
@@ -68,16 +75,33 @@ def test_selection_kernel_compiles(one_chip, block):
     assert _has_kernel(fn.lower(_sds(one_chip, ROWS)).compile())
 
 
-def test_sgd_kernel_compiles(one_chip):
-    """The SGD kernel with its labels and model as 2-D blocks, at the GLM
-    table's shape."""
-    fn = jax.jit(lambda a, b, x: sgd_pallas(a, b, x, lr=0.1, minibatch=16,
-                                            epochs=2, kind="logreg"))
-    m, d = 1 << 20, 8
-    compiled = fn.lower(_sds(one_chip, m, d, dtype=jnp.float32),
-                        _sds(one_chip, m, dtype=jnp.float32),
-                        _sds(one_chip, d, dtype=jnp.float32)).compile()
+@pytest.mark.parametrize("kind", ["logreg", "ridge"])
+def test_sgd_kernel_compiles(one_chip, kind):
+    """The streamed trainer's K-model kernel at the benchmark cell's
+    shape: 11,000,000 feature-major rows of 28 features and the label,
+    4 models, minibatches of 16, lr/l2 as operands."""
+    m, d, k = 11_000_000, 28, 4
+    fn = jax.jit(lambda data, lr, l2, x: sgd_block(data, lr, l2, x,
+                                                   minibatch=16, kind=kind))
+    compiled = fn.lower(_sds(one_chip, d + 1, m, dtype=jnp.float32),
+                        _sds(one_chip, k, dtype=jnp.float32),
+                        _sds(one_chip, k, dtype=jnp.float32),
+                        _sds(one_chip, k, d, dtype=jnp.float32)).compile()
     assert _has_kernel(compiled)
+    assert "sgd_block" in compiled.as_text()
+
+
+def test_trainer_takes_the_kernel_on_one_tpu_device(topo):
+    """The streamed trainer's dispatch rule: a plan over one TPU device
+    runs the kernel; a plan over the CPU, or over several devices (the
+    dataset replicated, a custom call GSPMD would have to partition),
+    runs the XLA loop."""
+    def mesh(devices):
+        return Mesh(np.array(devices), ("engine",))
+
+    assert engine.sgd_kernel_applies(mesh(topo.devices[:1]))
+    assert not engine.sgd_kernel_applies(mesh(topo.devices[:4]))
+    assert not engine.sgd_kernel_applies(mesh(jax.devices("cpu")[:1]))
 
 
 def test_planner_offers_pallas_only_where_a_kernel_compiles():
