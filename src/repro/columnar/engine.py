@@ -8,12 +8,14 @@ like MonetDB's optimizer picks the UDF implementation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.columnar.table import Column, MorselSpec, Table
@@ -23,7 +25,8 @@ from repro.core import sgd_glm
 from repro.core.channels import ChannelPlan
 from repro.kernels.join import ref as join_ref
 from repro.kernels.sgd import ref as sgd_ref
-from repro.kernels.sgd.sgd import sgd_block
+from repro.kernels.sgd import sgd as sgd_kernels
+from repro.kernels.sgd.sgd import sgd_block, sgd_block_wide
 
 
 # rows per engine block of the eager selection (``Executor._filter_table``
@@ -309,12 +312,40 @@ def aggregate_sum_stream(carry, values: jax.Array, mask: jax.Array,
 
 
 def sgd_kernel_applies(mesh) -> bool:
-    """Whether ``train_glm_stream`` runs its SGD loop as the Pallas kernel
-    (``kernels/sgd/sgd.sgd_block``): on a mesh of one TPU device.  The CPU
-    runs the XLA loop, and so does a mesh of several devices, where the
-    dataset is replicated and GSPMD would have to partition a custom
-    call.  The two paths stage the morsel in different layouts."""
+    """Whether ``train_glm_stream`` runs its SGD loop as a Pallas kernel
+    (``kernels/sgd/sgd.sgd_block``, or ``sgd_block_wide`` for a wide
+    table, ``sgd.wide``): on a mesh of one TPU device.  The CPU runs the
+    XLA loop, and so does a mesh of several devices, where the dataset is
+    replicated and GSPMD would have to partition a custom call.  The
+    paths stage the morsel in different layouts (``stage_morsel``)."""
     return mesh.devices.size == 1 and mesh.devices.flat[0].platform == "tpu"
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "rows_pad", "layout"))
+def stage_morsel(cols, start, *, rows: int, rows_pad: int, layout: str):
+    """One morsel of the training columns (the label last) as the step
+    programs take it, in one program: ``rows`` rows of each column from
+    ``start``, cast to float32, zero rows up to ``rows_pad``, and
+    ``layout``: ``"rows"`` gives ``(rows_pad, features)`` and the label
+    (the XLA loop), ``"features"`` gives ``(features + 1, rows_pad)`` with
+    the label as the last row (``sgd_block``), ``"wide"`` the same with
+    zero rows up to ``sgd.wide_rows`` (``sgd_block_wide``)."""
+    vals = [jnp.pad(lax.dynamic_slice_in_dim(c, start, rows)
+                    .astype(jnp.float32), (0, rows_pad - rows))
+            for c in cols]
+    if layout == "rows":
+        return jnp.stack(vals[:-1], axis=1), vals[-1]
+    if layout == "features":
+        return (jnp.stack(vals, axis=0),)
+    # XLA splits a concatenate of thousands of operands into nested ones,
+    # each byte written twice, so the rows go in place; eight at a time,
+    # since a row alone is one sublane of every (8, 128) tile it touches
+    data = jnp.zeros((sgd_kernels.wide_rows(len(cols) - 1), rows_pad),
+                     jnp.float32)
+    for i in range(0, len(vals), 8):
+        data = lax.dynamic_update_slice_in_dim(
+            data, jnp.stack(vals[i:i + 8]), i, 0)
+    return (data,)
 
 
 def train_glm_stream(table: Table, features: Sequence[str], label: str,
@@ -344,22 +375,27 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
 
     On one TPU chip (``sgd_kernel_applies``) each morsel is staged
     feature-major, ``(features + 1, rows)`` with the label as the last
-    row, and the SGD loop is the Pallas kernel ``sgd_block``; elsewhere
-    the morsel is ``(rows, features)`` plus the label and the loop is
-    ``sgd_ref`` under XLA.  Both apply one update per minibatch of the
-    morsel's rows padded to the minibatch; the kernel sums its products
-    in float32 in another order, so its weights match ``train_glm`` to
-    float32 rounding, not bit for bit.
+    row, and the SGD loop is a Pallas kernel: ``sgd_block``, or for a wide
+    table (``kernels/sgd/sgd.wide``, from the feature count)
+    ``sgd_block_wide`` over the same layout padded to whole 128-lane
+    groups; elsewhere the morsel is ``(rows, features)`` plus the label
+    and the loop is ``sgd_ref`` under XLA.  All apply one update per
+    minibatch of the morsel's rows padded to the minibatch; the kernels
+    sum their products in float32 in another order, so their weights
+    match ``train_glm`` to float32 rounding, not bit for bit.  Staging is
+    one program, ``stage_morsel``, traced once per morsel shape.
 
     Spans of ``telemetry`` (any object whose ``span(name, **attrs)`` is
-    a context manager): ``trainer.stage`` per morsel staged (slice, casts,
-    stack, ``device_put``), ``trainer.epoch_step`` per morsel and epoch and
+    a context manager): ``trainer.stage`` per morsel staged (with
+    ``cols`` and the staged ``bytes``; ``stage_morsel`` and
+    ``device_put``), ``trainer.epoch_step`` per morsel and epoch and
     ``trainer.loss_step`` per morsel, each around the step's dispatch,
     including any trace or compile of its program; ``trainer.epoch_step``
-    carries ``impl="pallas"`` or ``"xla"``.  Each trace of either step
-    opens a ``trainer.trace`` span and counts one ``trainer.traces`` in
-    ``metrics``; each kernel dispatch counts one
-    ``trainer.sgd_kernel_calls``."""
+    carries ``impl="pallas"``, ``"pallas_wide"`` or ``"xla"``.  Each trace
+    of either step opens a ``trainer.trace`` span and counts one
+    ``trainer.traces`` in ``metrics``; each kernel dispatch counts one
+    ``trainer.sgd_kernel_calls``, each morsel staged its bytes in
+    ``trainer.staged_bytes``."""
     m = table.num_rows
     if morsel_rows is None:
         morsel_rows = m
@@ -373,7 +409,10 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     xs = jnp.zeros((k, len(features)), jnp.float32)
     rep = NamedSharding(plan.mesh, P())      # dataset replication (Fig. 10a)
     kernel = sgd_kernel_applies(plan.mesh)
-    impl = "pallas" if kernel else "xla"
+    wide = kernel and sgd_kernels.wide(len(features))
+    impl = "pallas_wide" if wide else ("pallas" if kernel else "xla")
+    layout = "wide" if wide else ("features" if kernel else "rows")
+    on_device = all(table.column_tier(c) == "device" for c in cols)
 
     def traced():
         # runs in the steps' Python bodies, so once per trace; the span
@@ -383,21 +422,27 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
                 metrics.inc("trainer.traces")
 
     def morsel_arrays(i):
-        with telemetry.span("trainer.stage", morsel=i):
+        start, stop = spec.bounds(i)
+        n_valid = stop - start
+        # keep only up to the next minibatch multiple past the valid rows
+        rows_pad = -(-n_valid // minibatch) * minibatch
+        n_rows = (sgd_kernels.wide_rows(len(features)) if wide
+                  else len(cols))
+        n_bytes = 4 * n_rows * rows_pad
+        with telemetry.span("trainer.stage", morsel=i, cols=len(cols),
+                            bytes=n_bytes):
             t0 = time.perf_counter()
-            data, n_valid = table.morsel(spec, i, cols)
-            # Table.morsel pads the ragged tail to spec.rows; keep only up
-            # to the next minibatch multiple past the valid rows
-            rows_pad = -(-n_valid // minibatch) * minibatch
-            vals = [jnp.asarray(data[c][:rows_pad]).astype(jnp.float32)
-                    for c in cols]
-            if kernel:
-                # the kernel's layout: rows on the lanes, the label as
-                # the last feature row, one block DMA for both
-                arrays = (jnp.stack(vals, axis=0),)
+            if on_device:
+                # sliced inside the staging program: no dispatch per column
+                srcs = tuple(table.columns[c].data for c in cols)
             else:
-                arrays = (jnp.stack(vals[:-1], axis=1), vals[-1])
+                data, _ = table.morsel(spec, i, cols)
+                srcs, start = tuple(data[c] for c in cols), 0
+            arrays = stage_morsel(srcs, start, rows=n_valid,
+                                  rows_pad=rows_pad, layout=layout)
             arrays = tuple(jax.device_put(x, rep) for x in arrays)
+            if metrics is not None:
+                metrics.inc("trainer.staged_bytes", n_bytes)
             if on_morsel is not None:
                 jax.block_until_ready(arrays)
                 tiers = {table.column_tier(c) for c in cols}
@@ -410,6 +455,9 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     @jax.jit
     def epoch_step(xs, lrs, l2s, *arrays):
         traced()
+        if wide:
+            return sgd_block_wide(arrays[0], lrs, l2s, xs,
+                                  minibatch=minibatch, kind=kind)
         if kernel:
             return sgd_block(arrays[0], lrs, l2s, xs, minibatch=minibatch,
                              kind=kind)
@@ -434,9 +482,10 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
         if kernel:
             (d_m,) = arrays
             valid = (jnp.arange(d_m.shape[1]) < n_valid).astype(jnp.float32)
-            # the label row meets a zero weight
-            z = jnp.pad(xs, ((0, 0), (0, 1))) @ d_m
-            return acc + jnp.sum(row_loss(z, d_m[-1]) * valid, axis=1)
+            # the label row, and a wide layout's zero rows, meet zero weights
+            n = xs.shape[1]
+            z = jnp.pad(xs, ((0, 0), (0, d_m.shape[0] - n))) @ d_m
+            return acc + jnp.sum(row_loss(z, d_m[n]) * valid, axis=1)
         a_m, b_m = arrays
         valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
         return acc + jax.vmap(
@@ -449,7 +498,12 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
                                 impl=impl):
                 if kernel and metrics is not None:
                     metrics.inc("trainer.sgd_kernel_calls")
-                xs = epoch_step(xs, lrs, l2s, *arrays)
+                prev, xs = xs, epoch_step(xs, lrs, l2s, *arrays)
+            if kernel:
+                # the host stages ahead of the chip; waiting for the step
+                # before this one keeps two staged morsels in its HBM, not
+                # one for every step queued (3.3 GB each at epsilon's width)
+                jax.block_until_ready(prev)
     acc = jnp.zeros((k,), jnp.float32)
     for i in range(spec.n_morsels):
         arrays, n_valid = morsel_arrays(i)

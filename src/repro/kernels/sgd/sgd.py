@@ -157,3 +157,137 @@ def sgd_block(data, lr, l2, x0, *, minibatch: int = 16, epochs: int = 1,
       lr.astype(jnp.float32).reshape(k, 1, 1),
       l2.astype(jnp.float32).reshape(k, 1, 1), data, x0.astype(jnp.float32))
     return x[:, :n, 0]
+
+
+# -- the wide form ---------------------------------------------------------- #
+#
+# ``sgd_block`` holds each model broadcast over 128 lanes and does a whole
+# 128-row tile's arithmetic for each minibatch of 16, eight times the
+# minibatch's own, and its (K, n + 1, 128) models plus an (n + 1, 8192)
+# block no longer fit VMEM once n reaches a few hundred.  The wide form transposes each 128-row tile once, in VMEM,
+# to rows on sublanes and features on lanes, so a minibatch is its own 16
+# rows and a model one (1, features) row.
+
+# Feature rows (features + label) from which the trainer takes the wide
+# form.  Timed alone on one v5e (``benchmarks/sgd_forms.py``, K = 4,
+# 400,000 rows), us a minibatch step, sgd_block / sgd_block_wide: 28
+# features 0.192 / 0.298, 64 0.319 / 0.298, 127 0.458 / 0.299, 256 0.856 /
+# 0.327; at 2,000 sgd_block does not fit VMEM, the wide form 0.490.
+WIDE_FROM_ROWS = 65
+# bytes of one data block of the wide form; Pallas holds two
+WIDE_BLOCK_BYTES = 4 << 20
+
+
+def wide(n_features: int) -> bool:
+    """Whether the trainer runs ``sgd_block_wide`` (else ``sgd_block``)
+    for ``n_features`` features: the one place the form is chosen."""
+    return n_features + 1 >= WIDE_FROM_ROWS
+
+
+def wide_rows(n_features: int) -> int:
+    """Rows of the wide form's feature-major data: the features, the
+    label, and zero rows up to a whole number of 128-lane groups."""
+    return pl.cdiv(n_features + 1, LANES) * LANES
+
+
+def _sgd_wide_kernel(nmb_ref, lr_ref, l2_ref, data_ref, x0_ref, x_ref,
+                     rows_ref, label_ref, *, kind: str, minibatch: int,
+                     n_blocks: int, label: int):
+    step = pl.program_id(0)
+
+    @pl.when(step == 0)
+    def _init():
+        x_ref[...] = x0_ref[...]
+
+    block_rows = data_ref.shape[1]
+    per_tile = LANES // minibatch
+    per_block = block_rows // minibatch
+    todo = jnp.minimum(nmb_ref[0] - lax.rem(step, n_blocks) * per_block,
+                       per_block)
+    lr = lr_ref[...]                                   # (k, 1, 1)
+    l2x2 = 2.0 * l2_ref[...]
+    inv_mb = 1.0 / minibatch
+    group = label - label % LANES              # the label's lane group
+    lane = lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    is_label, is_feature = lane == label % LANES, lane != label % LANES
+
+    def tile(t, x):
+        """Tile t of the block, transposed to rows on sublanes; its labels
+        moved out to their own column, and zeroed in the features."""
+        rows_ref[...] = data_ref[
+            :, pl.ds(pl.multiple_of(t * LANES, LANES), LANES)].T
+        near = rows_ref[:, group:group + LANES]
+        label_ref[...] = jnp.sum(_zero_unless(is_label, near), axis=1,
+                                 keepdims=True)
+        rows_ref[:, group:group + LANES] = _zero_unless(is_feature, near)
+        return lax.fori_loop(
+            0, jnp.minimum(per_tile, todo - t * per_tile), update, x)
+
+    def update(i, x):
+        """Minibatch i of the tile applied to the k models x, (k, 1, n)."""
+        at = pl.ds(pl.multiple_of(i * minibatch, minibatch), minibatch)
+        a = rows_ref[at, :][None]                      # (1, mb, n)
+        z = jnp.sum(a * x, axis=2, keepdims=True)      # Dot, (k, mb, 1)
+        d = _link(kind, z) - label_ref[at, :][None]    # ScalarEngine
+        g = jnp.sum(a * d, axis=1, keepdims=True) * inv_mb
+        return x - lr * (g + l2x2 * x)                 # Update
+
+    x_ref[...] = lax.fori_loop(0, lax.div(todo + per_tile - 1, per_tile),
+                               tile, x_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "minibatch", "epochs", "kind", "block_bytes", "interpret"))
+def sgd_block_wide(data, lr, l2, x0, *, minibatch: int = 16,
+                   epochs: int = 1, kind: str = "ridge",
+                   block_bytes: int = WIDE_BLOCK_BYTES,
+                   interpret: bool = False):
+    """``sgd_block`` for wide tables: the same contract, updates and
+    order, the products and sums in float32 on the VPU.
+
+    data: (``wide_rows(n)``, m) float32, rows 0..n-1 the features, row n
+    the label and the rest zero, as ``engine.stage_morsel`` lays it out;
+    lr, l2: (K,); x0: (K, n).  Blocks of ``block_bytes`` (a multiple of
+    128 rows) stream HBM->VMEM."""
+    k, n = x0.shape
+    rows = wide_rows(n)
+    m = data.shape[1]
+    if data.shape[0] != rows:
+        raise ValueError(f"data has {data.shape[0]} rows, expected {n} "
+                         f"features + the label, padded to {rows}")
+    if m % minibatch or LANES % minibatch:
+        raise ValueError(f"{m} rows in minibatches of {minibatch}: need a "
+                         "whole number of minibatches, each dividing "
+                         f"{LANES} rows")
+    block_rows = max(block_bytes // (4 * rows) // LANES, 1) * LANES
+    block_rows = min(block_rows, pl.cdiv(m, LANES) * LANES)
+    n_blocks = pl.cdiv(m, block_rows)
+    kernel = functools.partial(_sgd_wide_kernel, kind=kind,
+                               minibatch=minibatch, n_blocks=n_blocks,
+                               label=n)
+    hyper = pl.BlockSpec((k, 1, 1), lambda i, nmb: (0, 0, 0))
+    # each model one row, features on the lanes; the label's and the pad
+    # lanes stay zero
+    models = pl.BlockSpec((k, 1, rows), lambda i, nmb: (0, 0, 0))
+    x = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(epochs * n_blocks,),
+            in_specs=[hyper, hyper,
+                      pl.BlockSpec((rows, block_rows),
+                                   lambda i, nmb: (0, lax.rem(i, n_blocks))),
+                      models],
+            out_specs=models,
+            scratch_shapes=[pltpu.VMEM((LANES, rows), jnp.float32),
+                            pltpu.VMEM((LANES, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((k, 1, rows), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),     # sequential: RAW dep
+        interpret=interpret,
+        name="sgd_block_wide",
+    )(jnp.full((1,), m // minibatch, jnp.int32),
+      lr.astype(jnp.float32).reshape(k, 1, 1),
+      l2.astype(jnp.float32).reshape(k, 1, 1), data,
+      jnp.pad(x0.astype(jnp.float32), ((0, 0), (0, rows - n)))[:, None])
+    return x[:, 0, :n]

@@ -19,7 +19,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.columnar import engine
 from repro.columnar.table import Column, Table
 from repro.kernels.selection import ops as sel_ops
-from repro.kernels.sgd.sgd import sgd_block
+from repro.kernels.sgd.sgd import sgd_block, sgd_block_wide, wide_rows
 from repro.query import logical as L
 from repro.query import pipeline as pl
 from repro.query.cost import ColumnStats, CostModel, TableStats, PALLAS_OPS
@@ -89,6 +89,23 @@ def test_sgd_kernel_compiles(one_chip, kind):
                         _sds(one_chip, k, d, dtype=jnp.float32)).compile()
     assert _has_kernel(compiled)
     assert "sgd_block" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["logreg", "ridge"])
+def test_sgd_wide_kernel_compiles(one_chip, kind):
+    """The wide form at the ``eps.train`` cell's shape: 400,000
+    feature-major rows of 2,000 features and the label, padded to 2,048
+    rows, 4 models, 10 epochs folded into the grid.  Its blocks, the
+    transposed tile and the models have to fit VMEM."""
+    m, d, k = 400_000, 2000, 4
+    fn = jax.jit(lambda data, lr, l2, x: sgd_block_wide(
+        data, lr, l2, x, minibatch=16, epochs=10, kind=kind))
+    compiled = fn.lower(_sds(one_chip, wide_rows(d), m, dtype=jnp.float32),
+                        _sds(one_chip, k, dtype=jnp.float32),
+                        _sds(one_chip, k, dtype=jnp.float32),
+                        _sds(one_chip, k, d, dtype=jnp.float32)).compile()
+    assert _has_kernel(compiled)
+    assert "sgd_block_wide" in compiled.as_text()
 
 
 def test_trainer_takes_the_kernel_on_one_tpu_device(topo):
