@@ -369,9 +369,9 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
 
     Morsels come from ``Table.morsel``, so host/disk-resident (spilled)
     columns stream tier-aware: the numpy slice + H2D promotion happens
-    per morsel and the training set never has to fit on device whole.
-    ``on_morsel(n_bytes, seconds, tier)`` observes each promotion, and
-    fences each morsel's staging to time it.
+    per morsel staged and the training set never has to fit on device
+    whole.  ``on_morsel(n_bytes, seconds, tier)`` observes each
+    promotion, and fences each staging to time it.
 
     On one TPU chip (``sgd_kernel_applies``) each morsel is staged
     feature-major, ``(features + 1, rows)`` with the label as the last
@@ -385,17 +385,25 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     match ``train_glm`` to float32 rounding, not bit for bit.  Staging is
     one program, ``stage_morsel``, traced once per morsel shape.
 
+    A training set of one morsel is staged once a call, and every epoch
+    and the loss pass read that one copy.  Several morsels are staged
+    anew for each epoch and for the loss pass, so only two staged
+    morsels need fit on the device at once: on the kernel path each
+    step that takes a fresh morsel first waits for the step before it.
+
     Spans of ``telemetry`` (any object whose ``span(name, **attrs)`` is
-    a context manager): ``trainer.stage`` per morsel staged (with
-    ``cols`` and the staged ``bytes``; ``stage_morsel`` and
-    ``device_put``), ``trainer.epoch_step`` per morsel and epoch and
-    ``trainer.loss_step`` per morsel, each around the step's dispatch,
-    including any trace or compile of its program; ``trainer.epoch_step``
-    carries ``impl="pallas"``, ``"pallas_wide"`` or ``"xla"``.  Each trace
-    of either step opens a ``trainer.trace`` span and counts one
-    ``trainer.traces`` in ``metrics``; each kernel dispatch counts one
-    ``trainer.sgd_kernel_calls``, each morsel staged its bytes in
-    ``trainer.staged_bytes``."""
+    a context manager): ``trainer.stage`` per staging (with ``cols`` and
+    the staged ``bytes``; ``stage_morsel`` and ``device_put``), so once
+    a call for one morsel, ``trainer.epoch_step`` per morsel and epoch
+    and ``trainer.loss_step`` per morsel, each around the step's
+    dispatch, including any trace or compile of its program;
+    ``trainer.epoch_step`` carries ``impl="pallas"``, ``"pallas_wide"``
+    or ``"xla"``.  Each trace of either step opens a ``trainer.trace``
+    span and counts one ``trainer.traces`` in ``metrics``; each kernel
+    dispatch counts one ``trainer.sgd_kernel_calls``, each staging its
+    bytes in ``trainer.staged_bytes``, and each step handed the morsel
+    already staged one ``trainer.stage_reuses`` (``epochs`` a call for
+    one morsel, none for several)."""
     m = table.num_rows
     if morsel_rows is None:
         morsel_rows = m
@@ -491,22 +499,37 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
         return acc + jax.vmap(
             lambda x: jnp.sum(row_loss(a_m @ x, b_m) * valid))(xs)
 
+    kept = None
+
+    def step_arrays(i):
+        # the step's morsel and whether it was staged for this step: a
+        # lone morsel is staged for the first step and kept for the rest
+        nonlocal kept
+        if kept is not None:
+            if metrics is not None:
+                metrics.inc("trainer.stage_reuses")
+            return kept, False
+        staged = morsel_arrays(i)
+        if spec.n_morsels == 1:
+            kept = staged
+        return staged, True
+
     for e in range(epochs):
         for i in range(spec.n_morsels):
-            arrays, _ = morsel_arrays(i)
+            (arrays, _), fresh = step_arrays(i)
             with telemetry.span("trainer.epoch_step", morsel=i, epoch=e,
                                 impl=impl):
                 if kernel and metrics is not None:
                     metrics.inc("trainer.sgd_kernel_calls")
                 prev, xs = xs, epoch_step(xs, lrs, l2s, *arrays)
-            if kernel:
+            if kernel and fresh:
                 # the host stages ahead of the chip; waiting for the step
                 # before this one keeps two staged morsels in its HBM, not
                 # one for every step queued (3.3 GB each at epsilon's width)
                 jax.block_until_ready(prev)
     acc = jnp.zeros((k,), jnp.float32)
     for i in range(spec.n_morsels):
-        arrays, n_valid = morsel_arrays(i)
+        (arrays, n_valid), _ = step_arrays(i)
         with telemetry.span("trainer.loss_step", morsel=i):
             acc = loss_step(acc, xs, jnp.int32(n_valid), *arrays)
     losses = acc / m + l2s * jnp.sum(jnp.square(xs), axis=1)

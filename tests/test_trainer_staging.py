@@ -72,7 +72,7 @@ def _query(n, grid, epochs=1):
 
 def test_staging_traces_once_across_queries():
     """Two queries of one shape, two grids: the staging program traces on
-    the first and is reused by the second; each morsel staged is a
+    the first and is reused by the second; each staging is a
     ``trainer.stage`` span with its columns and bytes, and the bytes are
     counted in ``trainer.staged_bytes``."""
     m, n = 1040, 5          # a shape no other test stages
@@ -85,11 +85,52 @@ def test_staging_traces_once_across_queries():
     assert sizes[1] == sizes[0] + 1 and sizes[2] == sizes[1]
     stages = [e["args"] for e in tel.tracer.events
               if e["name"] == "trainer.stage"]
-    # one morsel, staged for each of two epochs and for the losses
-    assert len(stages) == 6
+    # one morsel, staged once a query for both epochs and the losses
+    assert len(stages) == 2
     assert {(a["cols"], a["bytes"]) for a in stages} == {(n + 1,
                                                           4 * (n + 1) * m)}
-    assert ex.metrics.value("trainer.staged_bytes") == 6 * 4 * (n + 1) * m
+    assert ex.metrics.value("trainer.staged_bytes") == 2 * 4 * (n + 1) * m
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_one_morsel_is_staged_once_for_every_step(monkeypatch, impl):
+    """One morsel is staged once a call and its copy reused by the other
+    two epochs and the loss pass, and the weights and losses are still
+    ``train_glm``'s over the whole columns; three morsels are staged for
+    every step, as before, and none is reused.  ``pallas`` forces the
+    kernel's rule and interprets ``sgd_block``."""
+    m, n, epochs = 512, 3, 3
+    feats = [f"f{i}" for i in range(n)]
+    cat = _catalog(m, n, seed=1)
+    t, plan = cat.tables["g"], Executor(cat).plans["partitioned"]
+    grid = [HyperParams(0.1, 0.0), HyperParams(0.05, 0.01)]
+    xs_full, losses_full = engine.train_glm(t, feats, "y", grid, plan,
+                                            epochs=epochs)
+    if impl == "pallas":
+        monkeypatch.setattr(engine, "sgd_kernel_applies", lambda mesh: True)
+        monkeypatch.setattr(engine, "sgd_block",
+                            partial(engine.sgd_block, interpret=True))
+
+    def run(morsel_rows):
+        tel, reg = tm.Telemetry(enabled=True), tm.MetricsRegistry()
+        out = engine.train_glm_stream(t, feats, "y", grid, plan,
+                                      epochs=epochs, morsel_rows=morsel_rows,
+                                      telemetry=tel, metrics=reg)
+        names = [e["name"] for e in tel.tracer.events]
+        return out, names, reg
+
+    (xs, losses), names, reg = run(None)
+    assert names.count("trainer.stage") == 1
+    assert names.count("trainer.epoch_step") == epochs
+    assert reg.value("trainer.stage_reuses") == epochs
+    np.testing.assert_allclose(np.asarray(xs), np.asarray(xs_full),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(losses), np.asarray(losses_full),
+                               rtol=1e-4)
+
+    _, names, reg = run(208)            # morsels of 208, 208 and 96
+    assert names.count("trainer.stage") == 3 * (epochs + 1)
+    assert reg.value("trainer.stage_reuses") == 0
 
 
 def test_served_wide_path_matches_the_bench_reference(monkeypatch):
