@@ -1,9 +1,9 @@
 from repro.configs.base import (
-    SHAPES, SMOKE_SHAPE, ArchConfig, ShapeConfig, all_archs, dryrun_cells,
-    get_arch, smoke_config,
+    SHAPES, SMOKE_SHAPE, ArchConfig, ShapeConfig, all_archs, get_arch,
+    smoke_config,
 )
 
 __all__ = [
     "SHAPES", "SMOKE_SHAPE", "ArchConfig", "ShapeConfig", "all_archs",
-    "dryrun_cells", "get_arch", "smoke_config",
+    "get_arch", "smoke_config",
 ]

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Literal, Optional, Sequence
+from typing import Literal, Optional
 
 VOCAB_PAD = 128
 HEAD_PAD_MAX_WASTE = 0.25
@@ -318,14 +318,3 @@ def smoke_config(cfg: ArchConfig) -> ArchConfig:
 
 
 SMOKE_SHAPE = ShapeConfig("smoke", seq_len=64, global_batch=2, kind="train")
-
-
-def dryrun_cells(archs: Optional[Sequence[str]] = None):
-    """All (arch, shape) cells with skip metadata — 40 in total."""
-    cells = []
-    names = list(archs) if archs else sorted(all_archs())
-    for a in names:
-        cfg = get_arch(a)
-        for s in SHAPES.values():
-            cells.append((cfg, s, cfg.skip_reason(s)))
-    return cells

@@ -9,8 +9,8 @@ formula generalized), and can deliberately emit the CONGESTED placement
 partitioned" baselines.
 
 It also carries the paper's analytical bandwidth model, calibrated to the
-AD9H7 microbenchmark numbers, used by benchmarks/fig2 to reproduce the
-published curves and by the planner to predict layout quality on TPU.
+AD9H7 microbenchmark numbers (the published Fig. 2 curves), which the
+planner uses to predict layout quality on TPU.
 """
 from __future__ import annotations
 

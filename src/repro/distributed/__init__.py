@@ -1,3 +1,1 @@
-from repro.distributed import (  # noqa: F401
-    compression, context_parallel, pipeline, sharding,
-)
+from repro.distributed import sharding  # noqa: F401

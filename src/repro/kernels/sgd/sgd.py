@@ -169,10 +169,10 @@ def sgd_block(data, lr, l2, x0, *, minibatch: int = 16, epochs: int = 1,
 # rows and a model one (1, features) row.
 
 # Feature rows (features + label) from which the trainer takes the wide
-# form.  Timed alone on one v5e (``benchmarks/sgd_forms.py``, K = 4,
-# 400,000 rows), us a minibatch step, sgd_block / sgd_block_wide: 28
-# features 0.192 / 0.298, 64 0.319 / 0.298, 127 0.458 / 0.299, 256 0.856 /
-# 0.327; at 2,000 sgd_block does not fit VMEM, the wide form 0.490.
+# form.  Timed alone on one v5e (K = 4, 400,000 rows), us a minibatch
+# step, sgd_block / sgd_block_wide: 28 features 0.192 / 0.298, 64 0.319 /
+# 0.298, 127 0.458 / 0.299, 256 0.856 / 0.327; at 2,000 sgd_block does
+# not fit VMEM, the wide form 0.490.
 WIDE_FROM_ROWS = 65
 # bytes of one data block of the wide form; Pallas holds two
 WIDE_BLOCK_BYTES = 4 << 20

@@ -1,5 +1,5 @@
 """Placement of JAX's persistent compilation cache for the entry-point
-scripts (``chip_smoke.py``, ``benchmarks/``).  Importing the package never
+script ``chip_smoke.py``.  Importing the package never
 touches the cache; a script opts in by calling ``use_compile_cache``
 before its first compile."""
 from __future__ import annotations

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import ShardingRules
+from repro.models.sharding import ShardingRules
 from repro.models.common import apply_rope, la
 
 DENSE_MAX_SEQ = 2_048

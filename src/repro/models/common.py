@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.sharding import LogicalArray, ShardingRules
+from repro.models.sharding import LogicalArray, ShardingRules
 
 PARAM_DTYPE = jnp.bfloat16
 

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import LogicalArray, ShardingRules
+from repro.models.sharding import LogicalArray, ShardingRules
 from repro.models import attention as attn_mod
 from repro.models.attention import KVCache, attention, attn_params
 from repro.models.common import (
@@ -195,7 +195,7 @@ def decode_fn(cfg: ArchConfig, params, batch, caches, rules: ShardingRules,
 def count_units(cfg: ArchConfig, shape, rules: ShardingRules):
     """Stitched-count units (see transformer.count_units): one encoder layer
     and one decoder layer, each compiled standalone by the dry-run."""
-    from repro.distributed.sharding import tree_sds
+    from repro.models.sharding import tree_sds
     from repro.models import attention as attn_mod
 
     tp = rules.mesh.shape.get("model", 1)

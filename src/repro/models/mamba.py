@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import ShardingRules
+from repro.models.sharding import ShardingRules
 from repro.models.common import la
 
 # 128 (not 256): the intra-chunk (B,NC,nh,Q,Q) tensors scale with Q per

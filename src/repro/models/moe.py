@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import ShardingRules
+from repro.models.sharding import ShardingRules
 from repro.models.common import la, mlp_apply, mlp_params, _act
 
 

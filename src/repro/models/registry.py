@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.distributed.sharding import (
+from repro.models.sharding import (
     LogicalArray, ShardingRules, tree_sds, tree_shardings,
 )
 from repro.models import encdec, transformer

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.distributed.sharding import LogicalArray, ShardingRules
+from repro.models.sharding import LogicalArray, ShardingRules
 from repro.models import attention as attn_mod
 from repro.models import mamba as mamba_mod
 from repro.models.attention import KVCache, attention, attn_params
@@ -247,7 +247,7 @@ def decode_fn(cfg: ArchConfig, params, batch, caches, rules: ShardingRules,
 def count_units(cfg: ArchConfig, shape, rules: ShardingRules,
                 remat_policy: str = "nothing"):
     """Returns [(name, fn, args_sds, multiplier)] for the dry-run to compile."""
-    from repro.distributed.sharding import tree_sds   # local to avoid cycle
+    from repro.models.sharding import tree_sds   # local to avoid cycle
     from repro.models import attention as attn_mod
     from repro.models import mamba as mamba_mod
 

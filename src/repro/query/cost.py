@@ -31,8 +31,8 @@ BYTES_PER_VALUE = 4                 # int32/float32 columns
 
 # streaming efficiencies + fixed launch overheads (sec) per operator —
 # the crossover that makes the xla/pallas choice size-dependent.  These
-# are the DEFAULTS; numbers measured on the running device by
-# benchmarks/calibrate.py override them when passed in explicitly
+# are the DEFAULTS; numbers measured on the running device override them
+# when passed in explicitly
 # (``CostModel(calibration=load_calibration(path))``).
 XLA_STREAM_EFF = 0.70
 PALLAS_STREAM_EFF = 0.92
@@ -76,11 +76,11 @@ PALLAS_OPS = frozenset({"filter", "filter_project"})
 
 
 def load_calibration(path: str) -> dict:
-    """Measured stream efficiencies / call overheads as written by
-    ``benchmarks/calibrate.py``.  A calibration prices only the device it
-    was measured on, so a file whose ``backend`` — or ``device_kind``,
-    where it records one — differs from the running device's is refused
-    with ``ValueError``."""
+    """Measured stream efficiencies / call overheads from a JSON file in
+    the shape ``CostModel.calibration_snapshot`` returns.  A calibration
+    prices only the device it was measured on, so a file whose
+    ``backend`` — or ``device_kind``, where it records one — differs from
+    the running device's is refused with ``ValueError``."""
     with open(path) as f:
         data = json.load(f)
     if not isinstance(data, dict) or "backends" not in data:
@@ -90,7 +90,7 @@ def load_calibration(path: str) -> dict:
         raise ValueError(
             f"{path} was measured on backend {data.get('backend')!r}, "
             f"not on {dev.platform!r}: recalibrate on this device "
-            "(benchmarks/calibrate.py) or pass no calibration")
+            "or pass no calibration")
     if "device_kind" in data and data["device_kind"] != dev.device_kind:
         raise ValueError(
             f"{path} was measured on a {data['device_kind']!r}, not on a "
@@ -332,10 +332,11 @@ class CostModel:
         self.n_calibrations += 1
 
     def calibration_snapshot(self) -> dict:
-        """The model's CURRENT constants in ``BENCH_calibration.json``
-        shape — what the persistence layer writes so a warm-started server
-        re-applies exactly the calibration state this process converged to
-        (including any drift-triggered ledger overlays)."""
+        """The model's CURRENT constants in the calibration shape
+        ``load_calibration`` reads — what the persistence layer writes so
+        a warm-started server re-applies exactly the calibration state
+        this process converged to (including any drift-triggered ledger
+        overlays)."""
         snap = {"backend": self.calibrated_from or jax.default_backend(),
                 "backends": {impl: {"stream_eff": self.stream_eff[impl],
                                     "call_overhead_s":

@@ -4,9 +4,10 @@ The paper's serving story pays a cold-start tax twice over: the semantic
 cache re-materializes every bitmap/result from scratch, and the cost
 model re-converges its measured calibration overlay from a fresh ledger.
 This module serializes both — the cache's serializable residents plus a
-``BENCH_calibration.json``-shape snapshot of the model's current
-constants — into ONE ``.npz`` file, so a recycled ``QueryServer`` warms
-instantly instead of replaying its whole history.
+calibration-shaped snapshot of the model's current constants
+(``CostModel.calibration_snapshot``) — into ONE ``.npz`` file, so a
+recycled ``QueryServer`` warms instantly instead of replaying its whole
+history.
 
 Format: a single ``np.savez`` archive holding a ``manifest`` JSON string
 and one flat array per serialized buffer.  Entry keys are stored as

@@ -21,13 +21,13 @@ exists to close.  This module is the measurement layer:
 * **BandwidthLedger** — per physical operator, the cost model's
   predicted bytes/seconds next to measured bytes and fenced wall time
   (``jax.block_until_ready`` so execution is timed, not dispatch), with
-  drift ratios per op and a calibration overlay in exactly the shape
-  ``benchmarks/calibrate.py`` emits and ``CostModel(calibration=...)``
-  consumes — online recalibration is
+  drift ratios per op and a calibration overlay in the shape
+  ``CostModel(calibration=...)`` consumes (``{"backend", "backends":
+  {impl: {"stream_eff", "call_overhead_s"}}}`` plus optional tier-channel
+  keys such as ``h2d_gbps``) — online recalibration is
   ``model._apply_calibration(ledger.calibration_overlay(model))``.
 
-Spans are always on and never fence; ``benchmarks/span_cost.py`` times
-one with no profiler recording.  ``REPRO_TRACE=1`` (default off) adds the
+Spans are always on and never fence.  ``REPRO_TRACE=1`` (default off) adds the
 Chrome sink and the ledger, and the ledger does change the timing path:
 it fences each measured pipeline with ``jax.block_until_ready``.
 """

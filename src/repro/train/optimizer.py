@@ -12,7 +12,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import LogicalArray
+from repro.models.sharding import LogicalArray
 
 
 def _like(spec_tree, dtype):

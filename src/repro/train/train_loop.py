@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.distributed.sharding import ShardingRules, tree_sds
+from repro.models.sharding import ShardingRules, tree_sds
 from repro.models import registry
 from repro.train.optimizer import AdamW, PaperSGD
 
@@ -41,7 +41,7 @@ def make_decode_step(mb: registry.ModelBundle, rules: ShardingRules,
                      **kw) -> Callable:
     def decode_step(params, batch, caches):
         logits, new_caches = mb.decode_fn(params, batch, caches, rules, **kw)
-        # greedy token for the serving loop (sampling lives in launch/serve)
+        # greedy token for the serving loop (sampling is the caller's)
         next_tok = jnp.argmax(logits[..., :mb.cfg.vocab_size], axis=-1)
         return next_tok.astype(jnp.int32), logits, new_caches
     return decode_step

@@ -442,3 +442,60 @@ def test_register_tenant_pushes_shares_to_shared_cache(rng):
     srv.drain()
     tb = cache.stats_dict()["semantic_cache_tenant_bytes"]
     assert tb.get("hi", 0) > 0
+
+
+# --------------------------------------------------------------------------- #
+# soak: a mis-calibrated model served static and adaptive
+
+# "datasheet" calibration: near-ideal streaming and near-free dispatch,
+# so the model prices tiny morsels as free until the ledger corrects it
+DATASHEET = {"backend": "datasheet", "backends": {
+    "xla": {"stream_eff": 0.99, "call_overhead_s": 1e-9}}}
+
+
+@pytest.mark.parametrize("adaptive", [False, True],
+                         ids=["static", "adaptive"])
+def test_two_tenant_soak_matches_oracle(adaptive):
+    """Two tenants stream rounds of distinct filter+sum queries over a
+    mis-calibrated model, the best-effort one under backpressure; every
+    answer, before, during and after a drift-triggered re-plan, equals a
+    cache-free oracle's, and the adaptive server's final overlay applied
+    twice changes no price."""
+    n = 1 << 12
+    v = (np.arange(n) % 128).astype(np.int32)
+    w = np.ones(n, np.int32)
+
+    def catalog():
+        return Catalog.from_tables(Table.from_arrays(
+            "t", {"v": v.copy(), "w": w.copy()}))
+
+    ex = Executor(catalog(), telemetry=tm.Telemetry(enabled=True))
+    ex.cost_model.apply_calibration(DATASHEET)
+    policy = AdaptivePolicy(drift_threshold=1.0, k_windows=2,
+                            min_window_rows=4) if adaptive else None
+    # 8 morsels a query: advances after a pipeline's first feed the ledger
+    srv = QueryServer(ex, streaming=True, morsel_rows=512, policy=policy)
+    srv.register_tenant(TenantSpec("prio", priority=10, slo_p95_s=1e-9,
+                                   cache_share=2.0))
+    srv.register_tenant(TenantSpec("bulk", priority=0, cache_share=1.0))
+    queries = {}
+    for i in range(18):
+        q = Q.scan("t", ("v", "w")).filter("v", i % 96, i % 96 + 31) \
+             .sum("w")
+        queries[srv.submit(q, tenant="prio" if i % 2 == 0 else "bulk",
+                           deadline_s=5.0)] = q
+        if i % 6 == 5:
+            srv.drain()
+    oracle = Executor(catalog())
+    results = {rec.qid: rec.result for rec in srv.history}
+    assert set(results) == set(queries)
+    for qid, q in queries.items():
+        assert results[qid] == oracle.execute(q).value, q.node
+    assert srv.n_backpressured > 0
+    if adaptive:
+        assert srv.n_recalibrations > 0
+        overlay = ex.tel.ledger.calibration_overlay(ex.cost_model)
+        ex.cost_model.apply_calibration(overlay)
+        once = _prices(ex.cost_model)
+        ex.cost_model.apply_calibration(overlay)
+        assert _prices(ex.cost_model) == once

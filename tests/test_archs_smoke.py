@@ -7,7 +7,7 @@ import pytest
 
 from repro.configs import SHAPES, SMOKE_SHAPE, ShapeConfig, all_archs, \
     get_arch, smoke_config
-from repro.distributed.sharding import resolve
+from repro.models.sharding import resolve
 from repro.models import registry
 from repro.models.common import logits_fn
 

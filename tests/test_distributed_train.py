@@ -8,7 +8,7 @@ from _hyp import given, settings, st
 
 from repro.configs import SHAPES, get_arch, smoke_config
 from repro.distributed import compression
-from repro.distributed.sharding import resolve, tree_sds, validate_divisibility
+from repro.models.sharding import resolve, tree_sds, validate_divisibility
 from repro.launch.mesh import make_production_mesh  # noqa: F401 (not built here)
 from repro.models import registry
 from repro.train import checkpoint as ckpt
@@ -116,7 +116,7 @@ def test_elastic_restore_onto_host_mesh(host_mesh, tmp_path):
     with jax.set_mesh(host_mesh):
         params = mb.materialize_params(jax.random.key(0), tp=1)
         ckpt.save(tmp_path, 1, params)
-        from repro.distributed.sharding import tree_shardings
+        from repro.models.sharding import tree_shardings
         shardings = tree_shardings(mb.init_specs(1), rules)
         restored, _ = ckpt.restore(tmp_path, params, shardings=shardings)
         n1 = float(global_norm(params))

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.columnar.table import Column, Table
 from repro.core.sgd_glm import HyperParams
 from repro.query import logical as L
+from repro.query.cost import CostModel, plan_physical
 from repro.query.exec import Catalog, Executor, PlacementCapacityError
 from repro.query.serve import QueryServer
 from repro.query.tiering import TierBudgets
@@ -252,6 +253,19 @@ def test_sharded_pricing_offers_replicated_alternative():
     _, phys = ex.plan(train_q().node)
     assert "shard/replicated" in phys.alternatives
     assert "xla/congested" in phys.alternatives
+
+
+def test_sharded_train_prices_replication_below_congested():
+    """Fig. 10a's replication trade on an 8-shard mesh, in the model: for
+    an 8-model search the planner offers ``shard/replicated`` and prices
+    it below every job streaming one congested copy.  Pure cost-model
+    arithmetic — no devices needed."""
+    grid = [HyperParams(0.1 / (i + 1), 0.001 * i) for i in range(8)]
+    cat = Catalog.from_tables(make_table(8192))
+    phys = plan_physical(train_q(epochs=2, grid=grid).node, cat.stats,
+                         CostModel(8, n_shards=8))
+    alts = phys.alternatives
+    assert alts["shard/replicated"] < alts["xla/congested"], alts
 
 
 @pytest.mark.requires_mesh

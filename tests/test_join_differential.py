@@ -127,6 +127,22 @@ def test_pallas_and_xla_emit_identical_pair_lists(seed, dist_i):
     assert int(a.total) == int(b.total)
 
 
+@pytest.mark.parametrize("factor", [1, 4, 16])
+def test_multi_join_total_per_duplicate_factor(factor):
+    """Every build key repeated ``factor`` times and every probe key
+    present: each probe row emits exactly ``factor`` pairs, so the pair
+    total is probe rows x factor, with no overflow."""
+    r = np.random.default_rng(factor)
+    n_distinct = 64
+    s = np.repeat(np.arange(n_distinct, dtype=np.int32), factor)
+    l = r.integers(0, n_distinct, size=4 * N_L).astype(np.int32)
+    n_pairs = l.size * factor
+    res = hash_join_multi(jnp.asarray(s), jnp.asarray(l),
+                          max_out=ref.next_pow2(n_pairs + 1), impl="xla")
+    assert int(res.total) == n_pairs
+    assert not bool(res.overflowed)
+
+
 def test_empty_sides():
     for n_s, n_l in ((0, 256), (8, 0), (0, 0)):
         s = jnp.asarray(np.arange(n_s, dtype=np.int32))

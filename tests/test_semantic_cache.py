@@ -130,6 +130,27 @@ def test_result_cache_hit_skips_execution(rng):
 
 
 @pytest.mark.requires_cache
+@pytest.mark.parametrize("op", ["sum", "count", "mean"])
+def test_cached_templates_answer_as_cache_off(rng, op):
+    """Join+filter+aggregate templates (distinct bounds, so distinct
+    fingerprints) served from a warm cache answer exactly as an executor
+    with no cache computes them."""
+    cat, *_ = _make_catalog(rng)
+    cached = Executor(cat, cache_bytes=32 << 20)
+    plain = Executor(cat)
+    templates = [
+        Q.scan("big").join(Q.scan("small"), on="k")
+         .filter("v", lo, lo + 6).aggregate(op, "w")
+        for lo in (1, 30, 70)]
+    for q in templates:
+        assert not cached.execute(q).result_cache_hit
+    for q in templates:
+        hit = cached.execute(q)
+        assert hit.result_cache_hit
+        assert hit.value == plain.execute(q).value
+
+
+@pytest.mark.requires_cache
 def test_mutation_invalidates_differential(rng):
     """Acceptance: a base-table mutation provably invalidates dependent
     entries — post-mutation results are bit-identical to cache-disabled

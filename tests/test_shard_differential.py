@@ -193,6 +193,32 @@ def test_shard_layout_splits_fingerprint_and_cache_key(rng):
     assert exa._cache_key(na, pa) != exn._cache_key(nn, pn)
 
 
+@requires_mesh
+def test_sharded_glm_train_matches_single_device_bitwise():
+    """In-database GLM training on 8 shards (Fig. 10a's replicated
+    dataset) trains the same weights, bit for bit, as the 1-device eager
+    oracle: an 8-model search, 2 epochs over 8,192 rows of 4 features."""
+    _need_devices(8)
+    from repro.core.sgd_glm import HyperParams
+    r = np.random.default_rng(7)
+    n, feats = 1 << 13, ("f0", "f1", "f2", "f3")
+    a = r.normal(size=(n, len(feats))).astype(np.float32)
+    w = r.normal(size=(len(feats),)).astype(np.float32)
+    cols = {f: a[:, i] for i, f in enumerate(feats)}
+    cols["y"] = (1.0 / (1.0 + np.exp(-(a @ w))) > 0.5).astype(np.float32)
+    cols["k"] = np.arange(n, dtype=np.int32)
+
+    def catalog():
+        return Catalog.from_tables(Table.from_arrays("glm", cols))
+
+    grid = [HyperParams(0.1 / (i + 1), 0.001 * i) for i in range(8)]
+    q = Q.scan("glm").train_glm(list(feats), "y", grid, epochs=2)
+    oracle = Executor(catalog()).execute(q, optimized=False).value
+    got = Executor(catalog(), shards=8).execute(q).value
+    np.testing.assert_array_equal(np.asarray(got[0]),
+                                  np.asarray(oracle[0]))
+
+
 def _join_stats(probe: int, build: int):
     return {
         "l": TableStats(probe, ("pk", "v"),
@@ -226,6 +252,22 @@ def test_shuffle_broadcast_crossover_follows_cost_model():
         seen.add(j.shard_strategy)
     # the sweep must actually cross — both strategies win somewhere
     assert seen == {"broadcast", "shuffle"}
+
+
+def test_sharded_selection_cost_never_rises_with_shards():
+    """The channel-count sweep (Fig. 5) in the model: a filter+sum priced
+    on 1, 2, 4 and 8 shards, one engine each, never costs more with more
+    shards, and 8 shards cost less than one.  Pure cost-model
+    arithmetic — no devices needed."""
+    q = L.Aggregate(L.Filter(L.Scan("t", ("v", "w")), "v", 20, 69),
+                    "sum", "w")
+    stats = {"t": TableStats(1 << 17, ("v", "w"),
+                             {"v": ColumnStats(0, 99, 100),
+                              "w": ColumnStats(1, 9, 9)})}
+    costs = [plan_physical(q, stats, CostModel(k, n_shards=k)).total_cost_s
+             for k in (1, 2, 4, 8)]
+    assert all(b <= a for a, b in zip(costs, costs[1:])), costs
+    assert costs[-1] < costs[0]
 
 
 def test_shuffle_join_is_one_compiled_program(rng, caplog):

@@ -339,7 +339,7 @@ def test_stream_ledger_and_morsel_metrics():
 
 
 def test_calibration_overlay_feeds_cost_model():
-    """The ledger's overlay is consumable where calibrate.py's file is:
+    """The ledger's overlay is consumable where a calibration file is:
     recalibration is the documented one-liner."""
     tel = tm.Telemetry(enabled=True)
     cat, _ = _exact_catalog(1 << 12)
