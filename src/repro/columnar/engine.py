@@ -7,6 +7,7 @@ like MonetDB's optimizer picks the UDF implementation.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import time
@@ -15,6 +16,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -348,6 +350,71 @@ def stage_morsel(cols, start, *, rows: int, rows_pad: int, layout: str):
     return (data,)
 
 
+# the telemetry and metrics of the ``train_glm_stream`` call that is
+# dispatching the steps: a trace of a step reports to that call
+_TRACE_SINK: contextvars.ContextVar = contextvars.ContextVar(
+    "trainer_trace_sink", default=None)
+
+
+def _traced():
+    # runs in the steps' Python bodies, so once per trace; the span
+    # marks each trace in a profiler trace too
+    sink = _TRACE_SINK.get()
+    if sink is None:
+        return
+    telemetry, metrics = sink
+    with telemetry.span("trainer.trace"):
+        if metrics is not None:
+            metrics.inc("trainer.traces")
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("step", "impl", "kind", "minibatch"))
+def epoch_step(xs, lrs, l2s, *arrays, step, impl: str, kind: str,
+               minibatch: int):
+    """One epoch of the K models ``xs`` over one staged morsel: the
+    kernel ``step`` (``sgd_block`` or ``sgd_block_wide``) over the
+    feature-major morsel, or for ``impl="xla"`` ``step`` (``sgd_ref``)
+    under ``vmap`` over ``(rows, features)`` and the label.  ``step`` is
+    static, so a step function patched after a first trace gets a
+    program of its own."""
+    _traced()
+    if impl != "xla":
+        return step(arrays[0], lrs, l2s, xs, minibatch=minibatch, kind=kind)
+    a_m, b_m = arrays
+
+    def one(x, lr, l2):
+        return step(a_m, b_m, x, lr=lr, l2=l2, minibatch=minibatch,
+                    epochs=1, kind=kind)
+    return jax.vmap(one)(xs, lrs, l2s)
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "kind"))
+def loss_step(acc, xs, n_valid, *arrays, impl: str, kind: str):
+    """``acc`` plus each model's summed loss over the first ``n_valid``
+    rows of one staged morsel, in ``epoch_step``'s layout."""
+    _traced()
+
+    def row_loss(z, b):
+        if kind == "logreg":
+            p = jax.nn.sigmoid(z)
+            eps = 1e-7
+            return -(b * jnp.log(p + eps) + (1 - b) * jnp.log(1 - p + eps))
+        return 0.5 * jnp.square(z - b)
+
+    if impl != "xla":
+        (d_m,) = arrays
+        valid = (jnp.arange(d_m.shape[1]) < n_valid).astype(jnp.float32)
+        # the label row, and a wide layout's zero rows, meet zero weights
+        n = xs.shape[1]
+        z = jnp.pad(xs, ((0, 0), (0, d_m.shape[0] - n))) @ d_m
+        return acc + jnp.sum(row_loss(z, d_m[n]) * valid, axis=1)
+    a_m, b_m = arrays
+    valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
+    return acc + jax.vmap(
+        lambda x: jnp.sum(row_loss(a_m @ x, b_m) * valid))(xs)
+
+
 def train_glm_stream(table: Table, features: Sequence[str], label: str,
                      grid, plan: ChannelPlan, *, kind: str = "logreg",
                      epochs: int = 5, minibatch: int = 16,
@@ -383,7 +450,11 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     minibatch of the morsel's rows padded to the minibatch; the kernels
     sum their products in float32 in another order, so their weights
     match ``train_glm`` to float32 rounding, not bit for bit.  Staging is
-    one program, ``stage_morsel``, traced once per morsel shape.
+    one program, ``stage_morsel``, traced once per morsel shape.  The
+    steps are module-level programs, ``epoch_step`` and ``loss_step``,
+    each traced once per morsel shape, ``kind``, ``minibatch``, kernel
+    form and step function, and reused by every later call: the grid's
+    rates and the carry are operands.
 
     A training set of one morsel is staged once a call, and every epoch
     and the loss pass read that one copy.  Several morsels are staged
@@ -399,11 +470,12 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     dispatch, including any trace or compile of its program;
     ``trainer.epoch_step`` carries ``impl="pallas"``, ``"pallas_wide"``
     or ``"xla"``.  Each trace of either step opens a ``trainer.trace``
-    span and counts one ``trainer.traces`` in ``metrics``; each kernel
-    dispatch counts one ``trainer.sgd_kernel_calls``, each staging its
-    bytes in ``trainer.staged_bytes``, and each step handed the morsel
-    already staged one ``trainer.stage_reuses`` (``epochs`` a call for
-    one morsel, none for several)."""
+    span and counts one ``trainer.traces`` in ``metrics`` of the call
+    that traced it, so a call of a shape already traced counts none;
+    each kernel dispatch counts one ``trainer.sgd_kernel_calls``, each
+    staging its bytes in ``trainer.staged_bytes``, and each step handed
+    the morsel already staged one ``trainer.stage_reuses`` (``epochs`` a
+    call for one morsel, none for several)."""
     m = table.num_rows
     if morsel_rows is None:
         morsel_rows = m
@@ -412,22 +484,22 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
     spec = MorselSpec(m, morsel_rows)
     cols = tuple(features) + (label,)
     k = len(grid)
-    lrs = jnp.array([g.lr for g in grid], jnp.float32)
-    l2s = jnp.array([g.l2 for g in grid], jnp.float32)
-    xs = jnp.zeros((k, len(features)), jnp.float32)
     rep = NamedSharding(plan.mesh, P())      # dataset replication (Fig. 10a)
+    # committed like the staged morsels, so the first epoch runs the
+    # program the later ones do
+    xs, lrs, l2s, acc = jax.device_put(
+        (np.zeros((k, len(features)), np.float32),
+         np.array([g.lr for g in grid], np.float32),
+         np.array([g.l2 for g in grid], np.float32),
+         np.zeros((k,), np.float32)), rep)
     kernel = sgd_kernel_applies(plan.mesh)
     wide = kernel and sgd_kernels.wide(len(features))
     impl = "pallas_wide" if wide else ("pallas" if kernel else "xla")
     layout = "wide" if wide else ("features" if kernel else "rows")
+    # looked up on each call: a step patched since the last call runs
+    step = sgd_block_wide if wide else (sgd_block if kernel
+                                        else sgd_ref.sgd_ref)
     on_device = all(table.column_tier(c) == "device" for c in cols)
-
-    def traced():
-        # runs in the steps' Python bodies, so once per trace; the span
-        # marks each trace in a profiler trace too
-        with telemetry.span("trainer.trace"):
-            if metrics is not None:
-                metrics.inc("trainer.traces")
 
     def morsel_arrays(i):
         start, stop = spec.bounds(i)
@@ -460,45 +532,6 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
                           time.perf_counter() - t0, worst)
             return arrays, n_valid
 
-    @jax.jit
-    def epoch_step(xs, lrs, l2s, *arrays):
-        traced()
-        if wide:
-            return sgd_block_wide(arrays[0], lrs, l2s, xs,
-                                  minibatch=minibatch, kind=kind)
-        if kernel:
-            return sgd_block(arrays[0], lrs, l2s, xs, minibatch=minibatch,
-                             kind=kind)
-        a_m, b_m = arrays
-
-        def one(x, lr, l2):
-            return sgd_ref.sgd_ref(a_m, b_m, x, lr=lr, l2=l2,
-                                   minibatch=minibatch, epochs=1, kind=kind)
-        return jax.vmap(one)(xs, lrs, l2s)
-
-    @jax.jit
-    def loss_step(acc, xs, n_valid, *arrays):
-        traced()
-
-        def row_loss(z, b):
-            if kind == "logreg":
-                p = jax.nn.sigmoid(z)
-                eps = 1e-7
-                return -(b * jnp.log(p + eps) + (1 - b) * jnp.log(1 - p + eps))
-            return 0.5 * jnp.square(z - b)
-
-        if kernel:
-            (d_m,) = arrays
-            valid = (jnp.arange(d_m.shape[1]) < n_valid).astype(jnp.float32)
-            # the label row, and a wide layout's zero rows, meet zero weights
-            n = xs.shape[1]
-            z = jnp.pad(xs, ((0, 0), (0, d_m.shape[0] - n))) @ d_m
-            return acc + jnp.sum(row_loss(z, d_m[n]) * valid, axis=1)
-        a_m, b_m = arrays
-        valid = (jnp.arange(a_m.shape[0]) < n_valid).astype(jnp.float32)
-        return acc + jax.vmap(
-            lambda x: jnp.sum(row_loss(a_m @ x, b_m) * valid))(xs)
-
     kept = None
 
     def step_arrays(i):
@@ -514,23 +547,30 @@ def train_glm_stream(table: Table, features: Sequence[str], label: str,
             kept = staged
         return staged, True
 
-    for e in range(epochs):
+    sink = _TRACE_SINK.set((telemetry, metrics))
+    try:
+        for e in range(epochs):
+            for i in range(spec.n_morsels):
+                (arrays, _), fresh = step_arrays(i)
+                with telemetry.span("trainer.epoch_step", morsel=i, epoch=e,
+                                    impl=impl):
+                    if kernel and metrics is not None:
+                        metrics.inc("trainer.sgd_kernel_calls")
+                    prev, xs = xs, epoch_step(
+                        xs, lrs, l2s, *arrays, step=step, impl=impl,
+                        kind=kind, minibatch=minibatch)
+                if kernel and fresh:
+                    # the host stages ahead of the chip; waiting for the
+                    # step before this one keeps two staged morsels in its
+                    # HBM, not one for every step queued (3.3 GB each at
+                    # epsilon's width)
+                    jax.block_until_ready(prev)
         for i in range(spec.n_morsels):
-            (arrays, _), fresh = step_arrays(i)
-            with telemetry.span("trainer.epoch_step", morsel=i, epoch=e,
-                                impl=impl):
-                if kernel and metrics is not None:
-                    metrics.inc("trainer.sgd_kernel_calls")
-                prev, xs = xs, epoch_step(xs, lrs, l2s, *arrays)
-            if kernel and fresh:
-                # the host stages ahead of the chip; waiting for the step
-                # before this one keeps two staged morsels in its HBM, not
-                # one for every step queued (3.3 GB each at epsilon's width)
-                jax.block_until_ready(prev)
-    acc = jnp.zeros((k,), jnp.float32)
-    for i in range(spec.n_morsels):
-        (arrays, n_valid), _ = step_arrays(i)
-        with telemetry.span("trainer.loss_step", morsel=i):
-            acc = loss_step(acc, xs, jnp.int32(n_valid), *arrays)
+            (arrays, n_valid), _ = step_arrays(i)
+            with telemetry.span("trainer.loss_step", morsel=i):
+                acc = loss_step(acc, xs, jnp.int32(n_valid), *arrays,
+                                impl=impl, kind=kind)
+    finally:
+        _TRACE_SINK.reset(sink)
     losses = acc / m + l2s * jnp.sum(jnp.square(xs), axis=1)
     return xs, losses

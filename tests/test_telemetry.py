@@ -116,18 +116,18 @@ def test_chrome_trace_schema_and_nesting(tmp_path):
     assert execute["args"]["path"] == "batch"
 
 
-def _train_catalog(m=500, seed=0):
+def _train_catalog(m=500, seed=0, n=3):
     rng = np.random.default_rng(seed)
     cols = {f"f{i}": rng.normal(size=m).astype(np.float32)
-            for i in range(3)}
+            for i in range(n)}
     cols["y"] = (cols["f0"] - cols["f1"] > 0).astype(np.float32)
     return Catalog.from_tables(Table.from_arrays("g", cols))
 
 
-def _train_q(epochs=1):
-    return Q.scan("g").train_glm(["f0", "f1", "f2"], "y",
-                                 [HyperParams(0.1, 0.0),
-                                  HyperParams(0.05, 0.01)], epochs=epochs)
+def _train_q(epochs=1, n=3, grid=((0.1, 0.0), (0.05, 0.01))):
+    return Q.scan("g").train_glm([f"f{i}" for i in range(n)], "y",
+                                 [HyperParams(lr, l2) for lr, l2 in grid],
+                                 epochs=epochs)
 
 
 def _host_events(trace_dir, layers):
@@ -209,20 +209,23 @@ def test_fences_only_with_the_ledger(path, monkeypatch):
     assert calls and tel.ledger.rows
 
 
-def test_trainer_trace_count_repeats():
-    """Each call traces the trainer's two steps anew (fresh closures),
-    and the count repeats on a second run of the same query."""
-    ex = Executor(_train_catalog(), telemetry=tm.Telemetry(enabled=False))
+def test_trainer_traces_once_per_shape():
+    """The trainer's two steps are programs that outlive a call: the
+    first call traces each once per morsel shape, and a second call, of
+    the same grid or of another grid of its size, traces neither."""
+    n = 11                  # a feature count no other test trains on
+    ex = Executor(_train_catalog(n=n), telemetry=tm.Telemetry(enabled=False))
+    grids = [[(0.1, 0.0), (0.05, 0.01)], [(0.1, 0.0), (0.05, 0.01)],
+             [(0.02, 0.001), (0.2, 0.0)]]
     counts = []
-    for _ in range(2):
+    for grid in grids:
         before = ex.metrics.value("trainer.traces")
-        ex.execute(_train_q(epochs=2), morsel_rows=208)
+        ex.execute(_train_q(epochs=2, n=n, grid=grid), morsel_rows=208)
         counts.append(ex.metrics.value("trainer.traces") - before)
     # 500 rows in morsels of 208, 208 and 96 (the tail, padded to the
-    # minibatch).  Each step traces 3 times whatever the epochs: its first
-    # call takes the fresh zeros carry, not yet committed to a device,
-    # so the second 208-row morsel traces again; then the 96-row tail
-    assert counts == [6, 6]
+    # minibatch): two shapes, each traced once by each step, whatever the
+    # epochs, since the carry starts committed to the device
+    assert counts == [4, 0, 0]
 
 
 def _epoch_step_impls(tel):

@@ -133,6 +133,59 @@ def test_one_morsel_is_staged_once_for_every_step(monkeypatch, impl):
     assert reg.value("trainer.stage_reuses") == 0
 
 
+def _stream(t, feats, grid, plan, epochs):
+    reg = tm.MetricsRegistry()
+    xs, losses = engine.train_glm_stream(t, feats, "y", grid, plan,
+                                         epochs=epochs,
+                                         telemetry=tm.Telemetry(False),
+                                         metrics=reg)
+    return xs, losses, reg.value("trainer.traces")
+
+
+def test_reused_step_programs_leak_nothing_between_calls():
+    """The step programs outlive a call and capture nothing of it: two
+    grids on one table, then a table of other rows and features, each
+    give ``train_glm``'s weights and losses for their own grid and table,
+    and the program of the first table does not serve the second."""
+    epochs = 2
+    grids = [[HyperParams(0.1, 0.0), HyperParams(0.05, 0.01)],
+             [HyperParams(0.02, 0.001), HyperParams(0.3, 0.0)]]
+    runs = [(_catalog(528, 6, seed=4), grids[0]),
+            (_catalog(528, 6, seed=4), grids[1]),
+            (_catalog(336, 9, seed=5), grids[1])]
+    traces = []
+    for cat, grid in runs:
+        t, plan = cat.tables["g"], Executor(cat).plans["partitioned"]
+        feats = [c for c in t.columns if c != "y"]
+        xs, losses, n = _stream(t, feats, grid, plan, epochs)
+        traces.append(n)
+        xs_full, losses_full = engine.train_glm(t, feats, "y", grid, plan,
+                                                epochs=epochs)
+        np.testing.assert_allclose(np.asarray(xs), np.asarray(xs_full),
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(losses),
+                                   np.asarray(losses_full), rtol=1e-4)
+    assert traces[1] == 0 and traces[2] > 0
+
+
+def test_a_step_patched_after_the_first_trace_takes_effect(monkeypatch):
+    """The step function is part of the program's key: one patched after
+    the program was traced (as ``bench/faults.py`` plants a fault) runs
+    on the next call, and a step that returns its start leaves the
+    weights at zero."""
+    from repro.kernels.sgd import ref as sgd_ref
+    cat = _catalog(432, 4, seed=6)
+    t, plan = cat.tables["g"], Executor(cat).plans["partitioned"]
+    feats = [f"f{i}" for i in range(4)]
+    grid = [HyperParams(0.1, 0.0), HyperParams(0.05, 0.01)]
+    warm, _, _ = _stream(t, feats, grid, plan, 2)
+    assert np.all(np.abs(np.asarray(warm)).sum(axis=1) > 0)
+    monkeypatch.setattr(sgd_ref, "sgd_ref", lambda a, b, x0, **k: x0)
+    xs, _, n = _stream(t, feats, grid, plan, 2)
+    assert n > 0
+    np.testing.assert_array_equal(np.asarray(xs), np.zeros((2, 4)))
+
+
 def test_served_wide_path_matches_the_bench_reference(monkeypatch):
     """``QueryServer`` -> ``train_glm`` over a 2,048 x 300 table on the
     wide kernel (its rule forced, interpreted) equals the chip benchmark's
